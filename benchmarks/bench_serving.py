@@ -69,9 +69,9 @@ def _bench_paged_attention(
                        kv_heads, head_dim)
     x = jax.random.normal(jax.random.fold_in(key, 1), (batch, 1, d_model))
     pool_k = jax.random.normal(
-        jax.random.fold_in(key, 2), (n_pages, page_size, kv_heads, head_dim))
+        jax.random.fold_in(key, 2), (n_pages, kv_heads, page_size, head_dim))
     pool_v = jax.random.normal(
-        jax.random.fold_in(key, 3), (n_pages, page_size, kv_heads, head_dim))
+        jax.random.fold_in(key, 3), (n_pages, kv_heads, page_size, head_dim))
     tables = jnp.asarray(
         np.random.default_rng(0).permutation(
             np.arange(1, n_pages))[: batch * max_pages].reshape(

@@ -62,11 +62,7 @@ def _install_listener() -> None:
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        from jax import monitoring  # type: ignore[attr-defined]
-    except ImportError:  # pragma: no cover - old/new jax layouts
-        from jax._src import monitoring  # type: ignore[no-redef]
-    monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_listener(_on_event)
     _listener_installed = True
 
 

@@ -17,11 +17,12 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import re
-from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Mapping, Optional, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 __all__ = [
     "axis_rules",
@@ -32,67 +33,14 @@ __all__ = [
     "named_sharding_tree",
     "current_rules",
     "make_mesh",
-    "use_mesh",
-    "shard_map",
-    "cost_analysis",
-    "HAS_AXIS_TYPE",
 ]
 
-# ---------------------------------------------------------------------------
-# jax-version compatibility gate (AxisType landed after 0.4.x; set_mesh
-# likewise).  Everything downstream goes through these shims so the same
-# code runs on the pinned container jax and on current releases.
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType as _AxisType  # type: ignore
-    HAS_AXIS_TYPE = True
-except ImportError:
-    _AxisType = None
-    HAS_AXIS_TYPE = False
-
-
 def make_mesh(shape, axes, *, devices=None) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    kwargs = {}
-    if HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axes)
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(shape, axes, **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto``: logical constraints
+    steer GSPMD, no axis is in explicit-sharding mode."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
-
-@contextlib.contextmanager
-def use_mesh(mesh: Mesh):
-    """``jax.set_mesh`` when available, else the legacy ``with mesh:``
-    thread-resources context — either way ``_concrete_mesh`` sees it."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (check_vma) or the 0.4.x
-    ``jax.experimental.shard_map`` (check_rep), whichever is installed."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
-
-
-def cost_analysis(compiled) -> Dict[str, Any]:
-    """``Compiled.cost_analysis()`` normalized to a dict — pre-0.5 jax
-    returns a one-entry-per-program list."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 
@@ -131,8 +79,8 @@ def logical_constraint(x, *logical_axes: Optional[str]):
     rules = _RULES.get()
     if rules is None:
         return x
-    mesh = _current_mesh()
-    if mesh is None or mesh.empty:
+    mesh = _ambient_mesh()
+    if mesh is None:
         return x
     spec = []
     for dim, name in enumerate(logical_axes):
@@ -143,34 +91,11 @@ def logical_constraint(x, *logical_axes: Optional[str]):
     return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:       # pre-set_mesh jax: thread resources only
-        return _concrete_mesh()
-    if mesh is not None and not mesh.empty:
-        # constraints accept PartitionSpec directly under set_mesh
-        return _concrete_mesh() or mesh
-    return _concrete_mesh()
-
-
-def _concrete_mesh() -> Optional[Mesh]:
-    """Ambient mesh: `with mesh:` thread resources OR `jax.set_mesh(...)`."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+def _ambient_mesh() -> Optional[AbstractMesh]:
+    """The mesh installed by ``jax.set_mesh`` (visible inside jit), or
+    None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def make_train_rules(multi_pod: bool) -> Dict[str, AxisVal]:

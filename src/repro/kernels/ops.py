@@ -115,7 +115,7 @@ def paged_attention_decode(
     q: jnp.ndarray,            # (B, H, dh) — rotated query, new token
     k_new: jnp.ndarray,        # (B, K, dh) — rotated K, new token (in-register)
     v_new: jnp.ndarray,        # (B, K, dh)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh) physical pages
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) physical pages
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,   # (B, max_pages) int32 pool ids
     cache_len: jnp.ndarray,    # (B,) int32 — #prior tokens
@@ -140,7 +140,7 @@ def paged_attention_decode(
     jax.jit, static_argnames=("bm", "mode", "pages_per_step", "q_offset"))
 def paged_attention_prefill(
     q: jnp.ndarray,            # (B, S, H, dh) — rotated, pos [q_offset, q_offset+S)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh) — context K/V already
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) — context K/V already
     v_pool: jnp.ndarray,       #   scattered into the rows' pages
     page_table: jnp.ndarray,   # (B, max_pages) int32
     lengths: jnp.ndarray,      # (B,) int32 per-row TOTAL length (<= q_offset+S)

@@ -1,11 +1,15 @@
 """Fused paged-attention kernels: walk the page table, never gather.
 
 The serving pool stores every sequence's KV in fixed-size pages
-``(num_pages, page_size, K, dh)`` with a per-row table of page ids
-(serving/pages.py, DESIGN.md §9).  The naive decode path materializes the
-logical view first — ``pool[page_table].reshape(b, max_len, K, dh)`` —
-so every token pays O(max_pages · page_size) memory traffic no matter
-how short the row's real context is.  These kernels instead *walk* the
+``(num_pages, K, page_size, dh)`` with a per-row table of page ids
+(serving/pages.py, DESIGN.md §9).  Each (page, kv head) is one
+contiguous ``(page_size, dh)`` tile, so a kernel block covers whole
+trailing dims the TPU's Mosaic compiler can tile: ``page_size`` must be
+a multiple of the pool dtype's sublane count (8 for fp32, 16 for bf16).
+The naive decode path materializes the logical view first — a gather of
+``pool[page_table]`` to ``(b, max_len, K, dh)`` — so every token pays
+O(max_pages · page_size) memory traffic no matter how short the row's
+real context is.  These kernels instead *walk* the
 table: grid over (batch, kv_head), inner loop over pages, an
 online-softmax accumulator carried across pages, and the just-computed
 current token's K/V kept in-register (it seeds the accumulator and never
@@ -69,6 +73,25 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _segment(pool: jnp.ndarray, pid: jnp.ndarray) -> jnp.ndarray:
+    """Pages ``pid`` (B, n) of a (P, K, ps, dh) pool as one fp32
+    (B, K, n·ps, dh) run of logical positions."""
+    _, kvh, ps, dh = pool.shape
+    b, n = pid.shape
+    return pool[pid].transpose(0, 2, 1, 3, 4).reshape(
+        b, kvh, n * ps, dh).astype(jnp.float32)
+
+
+def _check_page_tiling(pool: jnp.ndarray) -> None:
+    """A (1, 1, page_size, dh) pool block is tiled by Mosaic only when
+    page_size fills whole sublane tiles of the pool dtype."""
+    sublanes = 8 * 4 // pool.dtype.itemsize
+    if pool.shape[2] % sublanes:
+        raise ValueError(
+            f"page_size {pool.shape[2]} is not a multiple of {sublanes}, the "
+            f"sublane tiling of a {pool.dtype} KV pool on TPU")
+
+
 # ---------------------------------------------------------------------------
 # Decode: one query token per row over [0, cache_len) pool positions
 # ---------------------------------------------------------------------------
@@ -77,8 +100,8 @@ def paged_attention_decode_ref(
     q: jnp.ndarray,            # (B, H, dh) — rotated query for the new token
     k_new: jnp.ndarray,        # (B, K, dh) — rotated K of the new token
     v_new: jnp.ndarray,        # (B, K, dh)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh) physical pages
-    v_pool: jnp.ndarray,       # (P, page_size, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) physical pages
+    v_pool: jnp.ndarray,       # (P, K, page_size, dh)
     page_table: jnp.ndarray,   # (B, max_pages) int32 pool ids
     cache_len: jnp.ndarray,    # (B,) int32 — #prior tokens (new token excluded)
     *,
@@ -92,7 +115,7 @@ def paged_attention_decode_ref(
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_pages = page_table.shape[1]
     scale = 1.0 / math.sqrt(dh)
     qg = q.reshape(b, kvh, g, dh).astype(jnp.float32)
@@ -120,22 +143,22 @@ def paged_attention_decode_ref(
         # clip the *lookup* (labels stay logical): positions past the
         # table are masked below, never mislabeled
         pid = jnp.take(page_table, jnp.minimum(idx, max_pages - 1), axis=1)
-        kp = k_pool[pid].reshape(b, seg, kvh, dh).astype(jnp.float32)
-        vp = v_pool[pid].reshape(b, seg, kvh, dh).astype(jnp.float32)
+        kp = _segment(k_pool, pid)                          # (B,K,seg,dh)
+        vp = _segment(v_pool, pid)
         pos = (idx[:, None] * ps + offs[None, :]).reshape(seg)
         valid = (pos[None, :] < clen[:, None]) & (pos[None, :] < max_pages * ps)
-        s = jnp.einsum("bkgd,bskd->bkgs", qg, kp,
+        s = jnp.einsum("bkgd,bksd->bkgs", qg, kp,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
         # zero masked values too: unallocated pages may hold anything
         # (NaN-poisoned in tests) and 0 · NaN = NaN in the contraction
-        vp = jnp.where(valid[:, :, None, None], vp, 0.0)
+        vp = jnp.where(valid[:, None, :, None], vp, 0.0)
         m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         r = jnp.exp(m - m2)
         p = jnp.where(valid[:, None, None, :], jnp.exp(s - m2), 0.0)
         l = l * r + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * r + jnp.einsum(
-            "bkgs,bskd->bkgd", p, vp, preferred_element_type=jnp.float32)
+            "bkgs,bksd->bkgd", p, vp, preferred_element_type=jnp.float32)
         return m2, l, acc
 
     n_steps = (jnp.max(clen) + seg - 1) // seg
@@ -157,17 +180,17 @@ def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
 
     @pl.when(j == 0)
     def _seed():
-        kn = kn_ref[0, 0].astype(jnp.float32)               # (dh,)
-        s_new = jnp.sum(qg * kn[None, :], axis=-1, keepdims=True) * scale
+        kn = kn_ref[0, 0].astype(jnp.float32)               # (1, dh)
+        s_new = jnp.sum(qg * kn, axis=-1, keepdims=True) * scale
         m_ref[...] = s_new                                  # (G, 1)
         l_ref[...] = jnp.ones_like(s_new)
         acc_ref[...] = jnp.broadcast_to(
-            vn_ref[0, 0].astype(jnp.float32)[None, :], acc_ref.shape)
+            vn_ref[0, 0].astype(jnp.float32), acc_ref.shape)
 
     @pl.when(j * page_size < clen)
     def _page():
-        kp = kp_ref[0, :, 0, :].astype(jnp.float32)         # (ps, dh)
-        vp = vp_ref[0, :, 0, :].astype(jnp.float32)
+        kp = kp_ref[0, 0].astype(jnp.float32)               # (ps, dh)
+        vp = vp_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             qg, kp, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (G, ps)
@@ -175,7 +198,9 @@ def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
             jnp.int32, (1, page_size), 1)
         valid = pos < clen                                  # (1, ps)
         s = jnp.where(valid, s, NEG_INF)
-        vp = jnp.where(valid.reshape(page_size, 1), vp, 0.0)
+        kv_live = (j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1), 0)) < clen
+        vp = jnp.where(kv_live, vp, 0.0)
         m = m_ref[...]
         m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         r = jnp.exp(m - m2)
@@ -194,8 +219,8 @@ def paged_attention_decode_pallas(
     q: jnp.ndarray,            # (B, H, dh)
     k_new: jnp.ndarray,        # (B, K, dh)
     v_new: jnp.ndarray,        # (B, K, dh)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh)
-    v_pool: jnp.ndarray,       # (P, page_size, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh)
+    v_pool: jnp.ndarray,       # (P, K, page_size, dh)
     page_table: jnp.ndarray,   # (B, max_pages) int32
     cache_len: jnp.ndarray,    # (B,) int32
     *,
@@ -204,10 +229,15 @@ def paged_attention_decode_pallas(
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_pages = page_table.shape[1]
     scale = 1.0 / math.sqrt(dh)
+    if not interpret:
+        _check_page_tiling(k_pool)
     qg = q.reshape(b, kvh, g, dh)
+    # a unit sublane dim keeps every block's trailing dims whole
+    kn = k_new.reshape(b, kvh, 1, dh)
+    vn = v_new.reshape(b, kvh, 1, dh)
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
 
@@ -216,20 +246,20 @@ def paged_attention_decode_pallas(
         # skips the DMA, so traffic is O(cache_len) not O(max_pages)
         live = (cl[bb] + ps - 1) // ps
         jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
-        return (tbl[bb, jj], 0, k, 0)
+        return (tbl[bb, jj], k, 0, 0)
 
+    row_map = lambda bb, k, j, tbl, cl: (bb, k, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bb, k, j, tbl, cl: (bb, k, 0, 0)),
-            pl.BlockSpec((1, 1, dh), lambda bb, k, j, tbl, cl: (bb, k, 0)),
-            pl.BlockSpec((1, 1, dh), lambda bb, k, j, tbl, cl: (bb, k, 0)),
-            pl.BlockSpec((1, ps, 1, dh), pool_map),
-            pl.BlockSpec((1, ps, 1, dh), pool_map),
+            pl.BlockSpec((1, 1, g, dh), row_map),
+            pl.BlockSpec((1, 1, 1, dh), row_map),
+            pl.BlockSpec((1, 1, 1, dh), row_map),
+            pl.BlockSpec((1, 1, ps, dh), pool_map),
+            pl.BlockSpec((1, 1, ps, dh), pool_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g, dh), lambda bb, k, j, tbl, cl: (bb, k, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, dh), row_map),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),     # running max m
             pltpu.VMEM((g, 1), jnp.float32),     # running normalizer l
@@ -247,7 +277,7 @@ def paged_attention_decode_pallas(
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(page_table, clen, qg, k_new, v_new, k_pool, v_pool)
+    )(page_table, clen, qg, kn, vn, k_pool, v_pool)
     return out.reshape(b, h, dh)
 
 
@@ -257,7 +287,7 @@ def paged_attention_decode_pallas(
 
 def paged_attention_prefill_ref(
     q: jnp.ndarray,            # (B, S, H, dh) — rotated, pos [q_offset, q_offset+S)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh) — prompt K/V scattered in
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) — prompt K/V scattered in
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,   # (B, max_pages) int32
     lengths: jnp.ndarray,      # (B,) int32 — per-row TOTAL length (<= q_offset+S)
@@ -274,9 +304,9 @@ def paged_attention_prefill_ref(
     per-row *total* context (prefix + tail); rows at/past their length
     get zero output.  Returns (B, S, H, dh) fp32."""
     b, s, h, dh = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[1]
     g = h // kvh
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_pages = page_table.shape[1]
     scale = 1.0 / math.sqrt(dh)
     qg = q.reshape(b, s, kvh, g, dh).transpose(0, 2, 3, 1, 4).astype(
@@ -295,23 +325,23 @@ def paged_attention_prefill_ref(
         m, l, acc = carry
         idx = j * pages_per_step + page_idx
         pid = jnp.take(page_table, jnp.minimum(idx, max_pages - 1), axis=1)
-        kp = k_pool[pid].reshape(b, seg, kvh, dh).astype(jnp.float32)
-        vp = v_pool[pid].reshape(b, seg, kvh, dh).astype(jnp.float32)
+        kp = _segment(k_pool, pid)                          # (B,K,seg,dh)
+        vp = _segment(v_pool, pid)
         kvpos = (idx[:, None] * ps + offs[None, :]).reshape(seg)
         # (B, S, seg): causal x per-row length, labels stay logical
         valid = ((kvpos[None, None, :] <= qpos[None, :, None])
                  & (kvpos[None, None, :] < ln[:, None, None])
                  & (qpos[None, :, None] < ln[:, None, None]))
         kv_live = kvpos[None, :] < ln[:, None]              # (B, seg)
-        sb = jnp.einsum("bkgqd,bskd->bkgqs", qg, kp,
+        sb = jnp.einsum("bkgqd,bksd->bkgqs", qg, kp,
                         preferred_element_type=jnp.float32) * scale
         sb = jnp.where(valid[:, None, None], sb, NEG_INF)
-        vp = jnp.where(kv_live[:, :, None, None], vp, 0.0)
+        vp = jnp.where(kv_live[:, None, :, None], vp, 0.0)
         m2 = jnp.maximum(m, jnp.max(sb, axis=-1, keepdims=True))
         r = jnp.exp(m - m2)
         p = jnp.where(valid[:, None, None], jnp.exp(sb - m2), 0.0)
         l = l * r + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * r + jnp.einsum("bkgqs,bskd->bkgqd", p, vp,
+        acc = acc * r + jnp.einsum("bkgqs,bksd->bkgqd", p, vp,
                                    preferred_element_type=jnp.float32)
         return m2, l, acc
 
@@ -324,9 +354,9 @@ def paged_attention_prefill_ref(
 def _prefill_kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
                     m_ref, l_ref, acc_ref, *, page_size: int, block_q: int,
                     group: int, scale: float, q_offset: int):
-    """Grid (B, K, q_tiles, pages), pages innermost.  Query rows are laid
-    out (bm·G, dh) so one dot covers the whole GQA group; the causal mask
-    is built from 2D iotas (qpos = q_offset + row // G, kvpos = page
+    """Grid (B, K, q_tiles, pages), pages innermost.  Query rows arrive
+    laid out (bm·G, dh) so one dot covers the whole GQA group; the causal
+    mask is built from 2D iotas (qpos = q_offset + row // G, kvpos = page
     offset) — ``q_offset`` shifts every query to its logical position for
     tail-only prefill over shared prefix pages (DESIGN.md §12)."""
     bb = pl.program_id(0)
@@ -345,16 +375,15 @@ def _prefill_kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
 
     @pl.when(j * page_size < qhi)
     def _page():
-        dh = acc_ref.shape[-1]
-        qg = q_ref[0, 0].astype(jnp.float32).reshape(block_q * group, dh)
-        kp = kp_ref[0, :, 0, :].astype(jnp.float32)         # (ps, dh)
-        vp = vp_ref[0, :, 0, :].astype(jnp.float32)
+        qg = q_ref[0, 0].astype(jnp.float32)                # (bm·G, dh)
+        kp = kp_ref[0, 0].astype(jnp.float32)               # (ps, dh)
+        vp = vp_ref[0, 0].astype(jnp.float32)
         sb = jax.lax.dot_general(
             qg, kp, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (bm·G, ps)
         shp = (block_q * group, page_size)
-        qpos = (q_offset + i * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, shp, 0) // group)
+        row = jax.lax.broadcasted_iota(jnp.int32, shp, 0)
+        qpos = q_offset + i * block_q + (row // group if group > 1 else row)
         kvpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
         valid = (kvpos <= qpos) & (kvpos < ln) & (qpos < ln)
         sb = jnp.where(valid, sb, NEG_INF)
@@ -373,13 +402,12 @@ def _prefill_kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
         l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).reshape(
-            o_ref.shape[2:])
+        o_ref[0, 0] = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
 
 
 def paged_attention_prefill_pallas(
     q: jnp.ndarray,            # (B, S, H, dh)
-    k_pool: jnp.ndarray,       # (P, page_size, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh)
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,   # (B, max_pages) int32
     lengths: jnp.ndarray,      # (B,) int32
@@ -389,11 +417,12 @@ def paged_attention_prefill_pallas(
     q_offset: int = 0,
 ) -> jnp.ndarray:
     b, s, h, dh = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[1]
     g = h // kvh
-    ps = k_pool.shape[1]
-    max_pages = page_table.shape[1]
+    ps = k_pool.shape[2]
     scale = 1.0 / math.sqrt(dh)
+    if not interpret:
+        _check_page_tiling(k_pool)
     bm = min(bm, s)
     s_pad = _cdiv(s, bm) * bm
     n_qt = s_pad // bm
@@ -403,23 +432,23 @@ def paged_attention_prefill_pallas(
     qt = q.reshape(b, s, kvh, g, dh).transpose(0, 2, 1, 3, 4)  # (B,K,S,G,dh)
     if s_pad != s:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+    qt = qt.reshape(b, kvh, s_pad * g, dh)           # GQA group rows adjacent
 
     def pool_map(bb, k, i, j, tbl, cl):
         live = (jnp.minimum(cl[bb], q_offset + (i + 1) * bm) + ps - 1) // ps
         jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
-        return (tbl[bb, jj], 0, k, 0)
+        return (tbl[bb, jj], k, 0, 0)
 
+    q_map = lambda bb, k, i, j, tbl, cl: (bb, k, i, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, n_qt, n_pg),
         in_specs=[
-            pl.BlockSpec((1, 1, bm, g, dh),
-                         lambda bb, k, i, j, tbl, cl: (bb, k, i, 0, 0)),
-            pl.BlockSpec((1, ps, 1, dh), pool_map),
-            pl.BlockSpec((1, ps, 1, dh), pool_map),
+            pl.BlockSpec((1, 1, bm * g, dh), q_map),
+            pl.BlockSpec((1, 1, ps, dh), pool_map),
+            pl.BlockSpec((1, 1, ps, dh), pool_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, bm, g, dh), lambda bb, k, i, j, tbl, cl: (bb, k, i, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, bm * g, dh), q_map),
         scratch_shapes=[
             pltpu.VMEM((bm * g, 1), jnp.float32),
             pltpu.VMEM((bm * g, 1), jnp.float32),
@@ -436,8 +465,9 @@ def paged_attention_prefill_pallas(
         functools.partial(_prefill_kernel, page_size=ps, block_q=bm,
                           group=g, scale=scale, q_offset=q_offset),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, s_pad, g, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, s_pad * g, dh), jnp.float32),
         interpret=interpret,
         **kwargs,
     )(page_table, ln, qt, k_pool, v_pool)
-    return out[:, :, :s].transpose(0, 2, 1, 3, 4).reshape(b, s, h, dh)
+    out = out.reshape(b, kvh, s_pad, g, dh)[:, :, :s]
+    return out.transpose(0, 2, 1, 3, 4).reshape(b, s, h, dh)

@@ -23,17 +23,12 @@ Usage:
       --out results/dryrun [--fresh-process] [--force]
 """
 import argparse
-import dataclasses
-import functools
 import json
 import subprocess
 import sys
 import time
 import traceback
-from typing import Any, Dict, Optional
-
-import jax
-import jax.numpy as jnp
+from typing import Any, Dict
 
 
 def _cell_id(arch: str, shape: str, multi_pod: bool, tag: str = "") -> str:
@@ -58,11 +53,15 @@ def _parse_overrides(spec: str) -> Dict[str, Any]:
 
 def run_cell(arch: str, shape: str, multi_pod: bool,
              overrides: Dict[str, Any] = None) -> Dict[str, Any]:
-    """Lower+compile one cell; returns the JSON-able result record."""
+    """Lower+compile one cell; returns the JSON-able result record.
+    JAX is imported here, in the process that compiles: under
+    ``--fresh-process`` the parent only dispatches children and never
+    initialises a backend of its own."""
+    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import SHAPES, get_config, input_specs, cell_applicable
-    from repro.distributed.sharding import axis_rules, use_mesh
+    from repro.distributed.sharding import axis_rules
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import analyze_compiled
     from repro.launch.specs import cell_shardings, rules_for_cell, tree_named
@@ -108,7 +107,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     from repro.optim.schedule import warmup_cosine
     lr = warmup_cosine(3e-4, 100, 10000)
 
-    with use_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         if cell.kind == "train":
             step = make_train_step(cfg, opt_cfg, lr)
             in_sh = (tree_named(shardings["state"], mesh),

@@ -188,9 +188,7 @@ def analyze_compiled(
     supplements: Optional[Dict[str, float]] = None,
     hw: HardwareSpec = TPU_V5E,
 ) -> RooflineRecord:
-    from repro.distributed.sharding import cost_analysis
-
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

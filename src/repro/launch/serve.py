@@ -112,9 +112,11 @@ def main() -> int:
     import numpy as np
 
     from repro.configs import get_config, make_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_caches, init_params, lm_generate, lm_prefill
     from repro.models.transformer import encode_kv_caches, encoder_forward
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = make_smoke(cfg)

@@ -34,10 +34,8 @@ def _sds(shape, dtype=jnp.float32):
 
 
 def _body_cost(fn, args) -> Tuple[float, float]:
-    from repro.distributed.sharding import cost_analysis
-
     compiled = jax.jit(fn).lower(*args).compile()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 

@@ -29,7 +29,7 @@ def main() -> int:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import SHAPES, get_config, input_specs
-    from repro.distributed.sharding import axis_rules, cost_analysis, use_mesh
+    from repro.distributed.sharding import axis_rules
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import _shape_bytes, parse_collectives
     from repro.launch.specs import cell_shardings, rules_for_cell, tree_named
@@ -57,7 +57,7 @@ def main() -> int:
     sh = cell_shardings(cfg, cell, mesh, args.multi_pod, specs,
                         state_shapes=state_shapes)
     rules = rules_for_cell(cell, mesh, args.multi_pod)
-    with use_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         if cell.kind == "train":
             fn = jax.jit(make_train_step(cfg, opt_cfg, warmup_cosine(3e-4, 100, 10000)),
                          in_shardings=(tree_named(sh["state"], mesh),
@@ -101,7 +101,7 @@ def main() -> int:
           f"{sum(agg.values())/1e9:.2f} GB; modeled wire: {wire/1e9:.2f} GB")
     for (op, name), nb in agg.most_common(args.top):
         print(f"{nb/1e9:8.3f}GB {op:18s} {name}")
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print(f"flops/dev={ca['flops']:.3e} bytes/dev={ca.get('bytes accessed',0):.3e}")
     return 0
 

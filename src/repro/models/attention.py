@@ -186,6 +186,15 @@ def _wo_project(p: Dict, o: jnp.ndarray, num_heads: int, head_dim: int,
     return dense(p["wo"], o.reshape(b, s, num_heads * head_dim), accum=accum)
 
 
+def _pages_view(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
+    """Gather a (P, K, ps, dh) pool through ``page_table`` (B, max_pages)
+    into the contiguous logical view (B, max_pages · ps, K, dh)."""
+    b, mp = page_table.shape
+    _, kvh, ps, dh = pool.shape
+    return pool[page_table].transpose(0, 1, 3, 2, 4).reshape(
+        b, mp * ps, kvh, dh)
+
+
 def attention_prefill(
     p: Dict,
     x: jnp.ndarray,                       # (B, S, D)
@@ -215,9 +224,9 @@ def attention_prefill(
     ``alloc`` tokens are kept, each at slot ``t % alloc`` — the same
     placement the per-token decode writes produce.
 
-    With ``page_table`` the cache is a ``(num_pages, page_size, K, dh)``
+    With ``page_table`` the cache is a ``(num_pages, K, page_size, dh)``
     pool (DESIGN.md §9/§10): token ``t`` of row ``b`` is scattered
-    straight into ``pool[table[b, t // ps], t % ps]``, then attention
+    straight into ``pool[table[b, t // ps], :, t % ps]``, then attention
     runs *over the pages themselves* with the fused bm-tiled page-walk
     kernel (kernels/paged_attention.py, DESIGN.md §11) — no contiguous
     logical view is ever materialized.  ``paged_impl="gather"`` keeps
@@ -264,12 +273,12 @@ def attention_prefill(
     alloc = cache["k"].shape[1]
     kc, vc = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
     if page_table is not None:
-        ps = cache["k"].shape[1]
+        ps = cache["k"].shape[2]
         t = jnp.arange(start_pos, start_pos + s)
         pid = page_table[:, t // ps]                   # (B, S) pool pages
         off = jnp.broadcast_to(t % ps, (b, s))
-        ck = cache["k"].at[pid, off].set(kc)
-        cv = cache["v"].at[pid, off].set(vc)
+        ck = cache["k"].at[pid, :, off].set(kc)
+        cv = cache["v"].at[pid, :, off].set(vc)
         total = start_pos + s                          # full logical context
         if paged_impl == "fused":
             # attend straight over the pages: the fused kernel walks this
@@ -283,9 +292,7 @@ def attention_prefill(
             # gather path with a prefix: materialize the logical view up
             # to the full context (every position < total is live), then
             # run the contiguous kernel with the query offset
-            mp = page_table.shape[1]
-            kv = ck[page_table].reshape(b, mp * ps, kv_heads, head_dim)
-            vv = cv[page_table].reshape(b, mp * ps, kv_heads, head_dim)
+            kv, vv = _pages_view(ck, page_table), _pages_view(cv, page_table)
             o = chunked_causal_attention(
                 q, kv[:, :total].astype(q.dtype), vv[:, :total].astype(q.dtype),
                 causal=True, window=None, chunk=chunk, q_offset=start_pos)
@@ -366,7 +373,7 @@ def attention_decode(
     over garbage KV.
 
     With ``page_table`` the cache is a *pool*: ``k``/``v`` are
-    ``(num_pages, page_size, K, dh)`` physical pages shared by all
+    ``(num_pages, K, page_size, dh)`` physical pages shared by all
     sequences, and row ``b`` reads/writes the logical slots named by
     ``page_table[b]`` (DESIGN.md §9).  The new token lands at page
     ``cache_len // page_size``, offset ``cache_len % page_size`` of its
@@ -396,7 +403,7 @@ def attention_decode(
                          "reads")
     if paged_impl not in ("fused", "gather"):
         raise ValueError(f"unknown paged_impl {paged_impl!r}")
-    page_size = cache["k"].shape[1]
+    page_size = cache["k"].shape[2] if paged else None
     max_len = page_table.shape[1] * page_size if paged else cache["k"].shape[1]
     ring = (not paged) and window is not None and max_len <= window
     q = _split_heads(dense(p["wq"], x), num_heads)          # (B,1,H,dh)
@@ -418,9 +425,9 @@ def attention_decode(
             pid = jnp.take_along_axis(
                 page_table, (cache_len // page_size)[:, None], axis=1)[:, 0]
             off = cache_len % page_size
-            ck = cache["k"].at[pid, off].set(
+            ck = cache["k"].at[pid, :, off].set(
                 knew[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[pid, off].set(
+            cv = cache["v"].at[pid, :, off].set(
                 vnew[:, 0].astype(cache["v"].dtype))
         else:
             rows = jnp.arange(b)
@@ -442,9 +449,8 @@ def attention_decode(
             b, 1, num_heads * head_dim))
         return o, cache
     if paged:
-        # pages gather: (B, max_pages, page, K, dh) -> (B, S_logical, K, dh)
-        ck = cache["k"][page_table].reshape(b, max_len, kv_heads, head_dim)
-        cv = cache["v"][page_table].reshape(b, max_len, kv_heads, head_dim)
+        ck = _pages_view(cache["k"], page_table)
+        cv = _pages_view(cache["v"], page_table)
     else:
         ck = logical_constraint(cache["k"], "batch", "kv_seq", "kv", None)
         cv = logical_constraint(cache["v"], "batch", "kv_seq", "kv", None)

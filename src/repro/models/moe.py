@@ -24,7 +24,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import _concrete_mesh, logical_constraint
+from repro.distributed.sharding import _ambient_mesh, logical_constraint
 from repro.kernels.ops import Epilogue
 from .layers import expert_matmul, matmul, truncated_normal_init
 
@@ -34,7 +34,7 @@ def _cap_axis_ok(num_experts: int) -> bool:
     the TP axis); under the intra-expert-TP fallback (mixtral E=8 < 16)
     it would fight the weights' own model-axis sharding — measured +88%
     collective on mixtral/train_4k (§Perf)."""
-    mesh = _concrete_mesh()
+    mesh = _ambient_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return False
     return num_experts % mesh.shape["model"] == 0

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.packing import BSRPlanes
-from repro.distributed.sharding import _concrete_mesh, current_rules, shard_map
+from repro.distributed.sharding import _ambient_mesh, current_rules
 from repro.kernels.ops import Epilogue, apply_epilogue, bsr_planes_matmul
 from repro.sparse.transform import planes_pspec
 
@@ -58,7 +58,7 @@ def _expert_mm(h: jnp.ndarray, w, *, epilogue=None) -> jnp.ndarray:
 
 
 def alltoall_available(num_experts: int) -> bool:
-    mesh = _concrete_mesh()
+    mesh = _ambient_mesh()
     rules = current_rules()
     if mesh is None or rules is None or "model" not in mesh.axis_names:
         return False
@@ -164,7 +164,7 @@ def moe_alltoall_apply(
     capacity_factor: float = 1.25,
     activation: str = "silu",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    mesh = _concrete_mesh()
+    mesh = _ambient_mesh()
     rules = current_rules()
     dp = rules.get("batch") or ()
     dp_axes = (dp,) if isinstance(dp, str) else tuple(dp)
@@ -195,10 +195,10 @@ def moe_alltoall_apply(
         pspec["experts_gate"] = planes_pspec(p["experts_gate"], "model")
     xspec = P(dp_axes if dp_axes else None, None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(xspec, pspec),
         out_specs=(xspec, P()),
-        check=False,
+        check_vma=False,
     )
     return fn(x, p)

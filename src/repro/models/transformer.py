@@ -442,7 +442,7 @@ def lm_decode(
 
     ``cache_len`` is a scalar or per-row ``(B,)`` vector (ragged prompts).
     With ``batch["page_tables"]`` (B, max_pages) the attention caches are
-    page pools — ``(num_pages, page_size, K, dh)`` — and every self-attn
+    page pools — ``(num_pages, K, page_size, dh)`` — and every self-attn
     layer reads/writes through the tables (DESIGN.md §9)."""
     tokens = batch["tokens"]
     b = tokens.shape[0]
@@ -525,7 +525,7 @@ def lm_prefill(
     Returns (fp32 logits (B, S, V), caches ready for ``cache_len=S``).
 
     With ``batch["page_tables"]`` (B, max_pages) the attention caches
-    are page pools — ``(num_pages, page_size, K, dh)`` — and every
+    are page pools — ``(num_pages, K, page_size, dh)`` — and every
     self-attn layer scatters its prompt K/V straight into the pages the
     rows own (paged prefill, DESIGN.md §10); recurrent and cross-attn
     caches are unaffected.

@@ -57,8 +57,6 @@ def compressed_psum_tree(grads, errors, mesh, axis_name: str = "pod",
     if axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1:
         return grads, errors
 
-    from repro.distributed.sharding import shard_map
-
     flat_g, td = jax.tree.flatten(grads)
     flat_e = td.flatten_up_to(errors)
     if pspecs is None:
@@ -68,10 +66,10 @@ def compressed_psum_tree(grads, errors, mesh, axis_name: str = "pod",
 
     out = []
     for g, e, spec in zip(flat_g, flat_e, flat_s):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda gs, es: compressed_psum(gs, es, axis_name),
             mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
-            check=False,
+            check_vma=False,
         )
         out.append(fn(g, e))
     return (

@@ -129,7 +129,8 @@ def _paged_prefill_step(params, tokens, caches, table, slot, *, cfg,
     engine gates this).  ``guard`` additionally reduces the first-token
     logits to an all-finite flag so admission can quarantine a poisoned
     prefill before it ever occupies a slot.  Returns
-    (first_token (1,), ok scalar bool, new caches)."""
+    (first_token (1,), ok scalar bool, first-token logits (1, V) fp32,
+    new caches)."""
     specs = layer_specs(cfg)
     row_caches = init_caches(cfg, 1, tokens.shape[1], jnp.float32)
     pre = [pool if spec.mixer == "attn" else rc
@@ -137,9 +138,9 @@ def _paged_prefill_step(params, tokens, caches, table, slot, *, cfg,
     logits, new = lm_prefill(
         params, pre, {"tokens": tokens, "page_tables": table}, cfg,
         start_pos=start)
-    first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    ok = (jnp.all(jnp.isfinite(logits[:, -1])) if guard
-          else jnp.asarray(True))
+    last = logits[:, -1]
+    first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    ok = jnp.all(jnp.isfinite(last)) if guard else jnp.asarray(True)
     out = []
     for spec, pool, nc in zip(specs, caches, new):
         if spec.mixer == "attn":
@@ -150,7 +151,7 @@ def _paged_prefill_step(params, tokens, caches, table, slot, *, cfg,
                 pool, nc))
         else:
             out.append(pool)
-    return first, ok, out
+    return first, ok, last, out
 
 
 @functools.partial(
@@ -245,7 +246,8 @@ class ServingEngine:
     cfg : model config.  Paged caches do not support SWA ring windows or
         encoder-decoder (whisper) stacks.
     num_slots : decode-batch rows; the jitted step shape never changes.
-    page_size : tokens per physical KV page.
+    page_size : tokens per physical KV page.  The compiled TPU kernels
+        need a multiple of 8 (the fp32 pool's sublane tiling).
     max_seq_len : longest prompt+generation budget a request may hold;
         fixes the page-table width.
     num_pages : physical pages per layer pool (page 0 is the null page).
@@ -374,9 +376,11 @@ class ServingEngine:
         for spec, c in zip(self._specs, init_caches(cfg, num_slots, 1,
                                                     jnp.float32)):
             if spec.mixer == "attn":
-                c = {"k": jnp.zeros((num_pages, page_size, kvh, hd),
+                # (page, kv head) is one (page_size, dh) tile: the block
+                # the paged-attention kernels DMA per grid step
+                c = {"k": jnp.zeros((num_pages, kvh, page_size, hd),
                                     jnp.float32),
-                     "v": jnp.zeros((num_pages, page_size, kvh, hd),
+                     "v": jnp.zeros((num_pages, kvh, page_size, hd),
                                     jnp.float32)}
             self.caches.append(c)
 
@@ -546,7 +550,7 @@ class ServingEngine:
             # token short of the prompt, so the tail is never empty and
             # every write lands past the shared region
             start = n_hit * self.pool.page_size
-            first, ok, self.caches = _paged_prefill_step(
+            first, ok, _, self.caches = _paged_prefill_step(
                 self.params, jnp.asarray(req.prompt[start:][None]),
                 self.caches, jnp.asarray(self._tables[slot][None]),
                 jnp.asarray(slot, jnp.int32), cfg=self.cfg, start=start,
@@ -833,6 +837,50 @@ class ServingEngine:
                 self.chunk_grows += 1
         self._last_chunk_ticks = ticks
 
+    def _chunk_call(self, left: np.ndarray, ticks: int):
+        """(args, static kwargs) of the ``_decode_chunk`` call for the
+        current host mirrors and per-slot budgets ``left``."""
+        args = (self.params, self.caches, jnp.asarray(self._tok),
+                jnp.asarray(self._cache_len), jnp.asarray(self._tables),
+                jnp.asarray(self._rngs), jnp.asarray(self._temp),
+                jnp.asarray(self._topk), jnp.asarray(self._topp),
+                jnp.asarray(left))
+        static = dict(cfg=self.cfg, ticks=ticks, eos_id=self.eos_id,
+                      sampled=bool(np.any(self._temp > 0.0)),
+                      guard=self.nan_guard)
+        return args, static
+
+    def lower_decode_chunk(self):
+        """The engine's decode chunk of ``ticks_per_sync`` steps lowered
+        for its current state (``jax.stages.Lowered``) — what ``step()``
+        would compile, for inspecting the program (e.g. that the Pallas
+        kernels are in it) without running it."""
+        args, static = self._chunk_call(
+            np.zeros((self.num_slots,), np.int32), self.ticks_per_sync)
+        return _decode_chunk.lower(*args, **static)
+
+    def prefill_logits(self, prompt) -> np.ndarray:
+        """First-token logits (V,) fp32 of ``prompt`` through the
+        admission path itself — the jitted paged prefill, writing into
+        freshly allocated pages of this engine's pool (and the recurrent
+        rows of a free slot), which go straight back to the pool.  For
+        checking the serving path against a reference forward."""
+        free = [i for i, s in enumerate(self.slots) if s is None]
+        if not free:
+            raise RuntimeError("prefill_logits needs a free slot")
+        prompt = np.asarray(prompt, np.int32).reshape(-1)
+        pages = self.pool.alloc_pages(self.pool.pages_for(prompt.size))
+        table = np.full((1, self.max_pages), NULL_PAGE, np.int32)
+        table[0, :len(pages)] = pages
+        try:
+            _, _, logits, self.caches = _paged_prefill_step(
+                self.params, jnp.asarray(prompt[None]), self.caches,
+                jnp.asarray(table), jnp.asarray(free[0], jnp.int32),
+                cfg=self.cfg, guard=self.nan_guard)
+        finally:
+            self.pool.free(pages)
+        return np.asarray(jax.device_get(logits))[0]
+
     def step(self) -> int:
         """One scheduler event: fault/lifecycle servicing, admission,
         then ONE on-device chunk of ``ticks_per_sync`` decode steps
@@ -858,14 +906,9 @@ class ServingEngine:
         try:
             if self.injector is not None:
                 self.injector.on_chunk_start(self, active, ticks)
+            args, static = self._chunk_call(left, ticks)
             toks, counts, bad, tok, clen, rngs, caches = _decode_chunk(
-                self.params, self.caches, jnp.asarray(self._tok),
-                jnp.asarray(self._cache_len), jnp.asarray(self._tables),
-                jnp.asarray(self._rngs), jnp.asarray(self._temp),
-                jnp.asarray(self._topk), jnp.asarray(self._topp),
-                jnp.asarray(left), cfg=self.cfg, ticks=ticks,
-                eos_id=self.eos_id, sampled=bool(np.any(self._temp > 0.0)),
-                guard=self.nan_guard)
+                *args, **static)
         except Exception as err:
             self._recover_chunk_failure(snap, err)
             self.tick += 1

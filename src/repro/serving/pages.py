@@ -2,10 +2,10 @@
 ref-counted sharing, and a content-hash prefix index.
 
 The physical cache for every attention layer is one pool array
-``(num_pages, page_size, kv_heads, head_dim)`` shared by all sequences;
+``(num_pages, kv_heads, page_size, head_dim)`` shared by all sequences;
 a sequence owns an ordered list of page ids (its *page table*) and its
 logical positions ``[0, cache_len)`` live at
-``pool[table[t // page_size], t % page_size]``.  The pool is the device
+``pool[table[t // page_size], :, t % page_size]``.  The pool is the device
 side; ``PagePool`` here is the host-side allocator that hands pages to
 sequences as they join and reclaims them as they finish (DESIGN.md §9).
 

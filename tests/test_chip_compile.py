@@ -1,0 +1,178 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+
+Interpret mode never checks Mosaic's block tiling, so these tests lower
+and compile every serving kernel at published model widths against a
+``v5e:2x2`` topology description: the TPU compiler runs on the host and
+refuses what the chip would refuse (unaligned blocks, VMEM overflow).
+Nothing executes.  Widths: qwen1.5-0.5b (H = K = 16, dh 64, d_ff 2816)
+and granite-moe-1b-a400m (H 16, K 8, 32 experts of d_ff 512).
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and every test worker
+imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.packing import BSRPlanes, BSRWeight
+from repro.core.structures import BlockingSpec
+from repro.kernels.block_sparse_matmul import (
+    bsr_matmul_pallas,
+    bsr_planes_matmul_pallas,
+)
+from repro.kernels.epilogue import Epilogue
+from repro.kernels.paged_attention import (
+    paged_attention_decode_pallas,
+    paged_attention_prefill_pallas,
+)
+
+BLOCK = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a described-chip compile can be written to the persistent cache
+    # but never read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU compiler"
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described chip; the Pallas kernel must be
+    in the program as a Mosaic custom call."""
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+# (h, kvh): qwen1.5-0.5b MHA and granite-moe-1b-a400m GQA; page sizes
+# at the sublane tiling of fp32 (8) and bf16 (16) pools
+ATTN_WIDTHS = [(16, 16), (16, 8)]
+POOLS = [(8, "float32"), (16, "float32"), (16, "bfloat16")]
+
+
+@pytest.mark.parametrize("ps,pool_dtype", POOLS)
+@pytest.mark.parametrize("h,kvh", ATTN_WIDTHS)
+def test_paged_decode_compiles_for_v5e(one_chip, h, kvh, ps, pool_dtype):
+    b, dh, max_seq = 8, 64, 1024
+    mp = max_seq // ps
+    n_pages = b * mp + 1
+    args = (
+        _sds((b, h, dh), "bfloat16", one_chip),
+        _sds((b, kvh, dh), "bfloat16", one_chip),
+        _sds((b, kvh, dh), "bfloat16", one_chip),
+        _sds((n_pages, kvh, ps, dh), pool_dtype, one_chip),
+        _sds((n_pages, kvh, ps, dh), pool_dtype, one_chip),
+        _sds((b, mp), "int32", one_chip),
+        _sds((b,), "int32", one_chip),
+    )
+    _compile(paged_attention_decode_pallas, *args)
+
+
+@pytest.mark.parametrize("s,bm,q_offset", [
+    (512, 512, 0),       # one query tile over a full-length prompt
+    (200, 200, 0),       # prompt length off the sublane grid
+    (512, 128, 0),       # bm-tiled query blocks
+    (72, 72, 128),       # prefix-cache tail over shared pages
+])
+@pytest.mark.parametrize("h,kvh", ATTN_WIDTHS)
+def test_paged_prefill_compiles_for_v5e(one_chip, h, kvh, s, bm, q_offset):
+    dh, ps, max_seq = 64, 16, 1024
+    mp = max_seq // ps
+    n_pages = mp + 1
+
+    def fn(q, kp, vp, tbl, ln):
+        return paged_attention_prefill_pallas(
+            q, kp, vp, tbl, ln, bm=bm, q_offset=q_offset)
+
+    _compile(fn,
+             _sds((1, s, h, dh), "bfloat16", one_chip),
+             _sds((n_pages, kvh, ps, dh), "float32", one_chip),
+             _sds((n_pages, kvh, ps, dh), "float32", one_chip),
+             _sds((1, mp), "int32", one_chip),
+             _sds((1,), "int32", one_chip))
+
+
+def test_paged_kernels_refuse_untileable_page_size(one_chip):
+    """A page size off the pool dtype's sublane tiling is refused up
+    front with the reason, not deep inside Mosaic."""
+    args = (
+        _sds((2, 4, 64), "float32", one_chip),
+        _sds((2, 4, 64), "float32", one_chip),
+        _sds((2, 4, 64), "float32", one_chip),
+        _sds((9, 4, 4, 64), "float32", one_chip),
+        _sds((9, 4, 4, 64), "float32", one_chip),
+        _sds((2, 4), "int32", one_chip),
+        _sds((2,), "int32", one_chip),
+    )
+    with pytest.raises(ValueError, match="sublane tiling"):
+        jax.jit(paged_attention_decode_pallas).lower(*args)
+
+
+def _bsr_spec(k, n, sharding, planes=None):
+    """Shape-only BSR weight at 50% tile density, 128x128 blocks."""
+    gk, gn = k // BLOCK, n // BLOCK
+    max_nnz = max(gk // 2, 1)
+    nnz = gn * max_nnz
+    lead = () if planes is None else (planes,)
+    fields = dict(
+        indices=_sds(lead + (gn, max_nnz), "int32", sharding),
+        slots=_sds(lead + (gn, max_nnz), "int32", sharding),
+        blocks=_sds(lead + (nnz, BLOCK, BLOCK), "bfloat16", sharding),
+        flat_rows=_sds(lead + (nnz,), "int32", sharding),
+        flat_cols=_sds(lead + (nnz,), "int32", sharding),
+        blocking=BlockingSpec(bk=BLOCK, bn=BLOCK),
+    )
+    if planes is None:
+        return BSRWeight(shape=(k, n), nnz_blocks=nnz, **fields)
+    return BSRPlanes(shape=(planes, k, n), plane_nnz=(nnz,) * planes,
+                     **fields)
+
+
+@pytest.mark.parametrize("epilogue", [False, True])
+@pytest.mark.parametrize("m", [8, 256])
+@pytest.mark.parametrize("k,n", [(1024, 2816), (2816, 1024)])
+def test_bsr_matmul_compiles_for_v5e(one_chip, k, n, m, epilogue):
+    w = _bsr_spec(k, n, one_chip)
+    x = _sds((m, k), "bfloat16", one_chip)
+    if not epilogue:
+        _compile(lambda x, w: bsr_matmul_pallas(x, w), x, w)
+        return
+    # the fused MLP tail: bias, SwiGLU gate multiply, residual
+    epi = Epilogue(bias=_sds((n,), "bfloat16", one_chip),
+                   multiplier=_sds((m, n), "bfloat16", one_chip),
+                   residual=_sds((m, n), "bfloat16", one_chip),
+                   activation="silu")
+    _compile(lambda x, w, e: bsr_matmul_pallas(x, w, epilogue=e), x, w, epi)
+
+
+@pytest.mark.parametrize("m", [8, 256])
+def test_bsr_planes_matmul_compiles_for_v5e(one_chip, m):
+    e, k, n = 32, 1024, 512
+    planes = _bsr_spec(k, n, one_chip, planes=e)
+    x = _sds((e, m, k), "bfloat16", one_chip)
+    epi = Epilogue(multiplier=_sds((e, m, n), "bfloat16", one_chip),
+                   activation="silu")
+    _compile(lambda x, w, ep: bsr_planes_matmul_pallas(x, w, epilogue=ep),
+             x, planes, epi)

@@ -40,16 +40,16 @@ def _mk_decode(rng, b, h, kvh, dh, ps, max_pages, clens, poison=False):
     ids = rng.permutation(np.arange(1, n_pages))[: b * max_pages]
     tbl = np.where(clens[:, None] == 0, 0, ids.reshape(b, max_pages))
     if poison:
-        kp = np.full((n_pages, ps, kvh, dh), np.nan, np.float32)
-        vp = np.full((n_pages, ps, kvh, dh), np.nan, np.float32)
+        kp = np.full((n_pages, kvh, ps, dh), np.nan, np.float32)
+        vp = np.full((n_pages, kvh, ps, dh), np.nan, np.float32)
         for r in range(b):                    # only live positions are real
             for t in range(int(clens[r])):
-                kp[tbl[r, t // ps], t % ps] = rng.normal(size=(kvh, dh))
-                vp[tbl[r, t // ps], t % ps] = rng.normal(size=(kvh, dh))
+                kp[tbl[r, t // ps], :, t % ps] = rng.normal(size=(kvh, dh))
+                vp[tbl[r, t // ps], :, t % ps] = rng.normal(size=(kvh, dh))
         kp, vp = jnp.asarray(kp), jnp.asarray(vp)
     else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)), jnp.float32)
     return (q, kn, vn, kp, vp, jnp.asarray(tbl, jnp.int32),
             jnp.asarray(clens, jnp.int32))
 
@@ -60,10 +60,10 @@ def _decode_oracle(q, kn, vn, kp, vp, tbl, clen):
     b, h, dh = q.shape
     kvh = kn.shape[1]
     g = h // kvh
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     s_max = tbl.shape[1] * ps
-    ck = np.array(kp[tbl].reshape(b, s_max, kvh, dh))
-    cv = np.array(vp[tbl].reshape(b, s_max, kvh, dh))
+    ck = np.array(kp[tbl].transpose(0, 1, 3, 2, 4).reshape(b, s_max, kvh, dh))
+    cv = np.array(vp[tbl].transpose(0, 1, 3, 2, 4).reshape(b, s_max, kvh, dh))
     for r in range(b):
         c = int(clen[r])
         ck[r, c] = np.asarray(kn[r])
@@ -149,8 +149,8 @@ def test_attention_decode_fused_matches_gather_impl():
     x = jax.random.normal(jax.random.fold_in(key, 1), (b, 1, d))
     n_pages = b * mp + 1
     pool = {
-        "k": jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)), jnp.float32),
-        "v": jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)), jnp.float32),
+        "k": jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)), jnp.float32),
+        "v": jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)), jnp.float32),
     }
     tables = jnp.asarray(
         rng.permutation(np.arange(1, n_pages))[: b * mp].reshape(b, mp),
@@ -182,13 +182,13 @@ def _mk_prefill(rng, b, s, h, kvh, dh, ps):
     v = jnp.asarray(rng.normal(size=(b, s, kvh, dh)), jnp.float32)
     tbl = jnp.asarray(
         rng.permutation(np.arange(1, n_pages)).reshape(b, mp), jnp.int32)
-    kp = jnp.full((n_pages, ps, kvh, dh), jnp.nan, jnp.float32)
-    vp = jnp.full((n_pages, ps, kvh, dh), jnp.nan, jnp.float32)
+    kp = jnp.full((n_pages, kvh, ps, dh), jnp.nan, jnp.float32)
+    vp = jnp.full((n_pages, kvh, ps, dh), jnp.nan, jnp.float32)
     t = jnp.arange(s)
     pid = tbl[:, t // ps]
     off = jnp.broadcast_to(t % ps, (b, s))
-    kp = kp.at[pid, off].set(k)
-    vp = vp.at[pid, off].set(v)
+    kp = kp.at[pid, :, off].set(k)
+    vp = vp.at[pid, :, off].set(v)
     return q, k, v, kp, vp, tbl
 
 

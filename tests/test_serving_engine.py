@@ -12,7 +12,8 @@ import pytest
 
 from repro.configs import get_config, make_smoke
 from repro.core import BlockingSpec
-from repro.models import init_caches, init_params, lm_generate, lm_prefill
+from repro.models import (init_caches, init_params, lm_forward, lm_generate,
+                          lm_prefill)
 from repro.models.attention import (
     attention_decode,
     attention_init,
@@ -193,12 +194,14 @@ def test_attention_decode_paged_matches_contiguous():
 
     # pool: rows own disjoint, deliberately non-contiguous page ids
     tables = jnp.asarray([[3, 1, 5], [2, 6, 4]], jnp.int32)
-    pool_k = jnp.zeros((7, ps, kvh, dh))
-    pool_v = jnp.zeros((7, ps, kvh, dh))
+    pool_k = jnp.zeros((7, kvh, ps, dh))
+    pool_v = jnp.zeros((7, kvh, ps, dh))
     for r in range(b):
         for j in range(npages_seq):
-            pool_k = pool_k.at[tables[r, j]].set(k0[r, j * ps:(j + 1) * ps])
-            pool_v = pool_v.at[tables[r, j]].set(v0[r, j * ps:(j + 1) * ps])
+            pool_k = pool_k.at[tables[r, j]].set(
+                k0[r, j * ps:(j + 1) * ps].transpose(1, 0, 2))
+            pool_v = pool_v.at[tables[r, j]].set(
+                v0[r, j * ps:(j + 1) * ps].transpose(1, 0, 2))
 
     out_p, cp = attention_decode(
         p, x, {"k": pool_k, "v": pool_v}, cache_len,
@@ -208,7 +211,7 @@ def test_attention_decode_paged_matches_contiguous():
     # the write landed in the right physical slot of each row's own page
     for r, L in enumerate([5, 9]):
         want = cc["k"][r, L]
-        got = cp["k"][tables[r, L // ps], L % ps]
+        got = cp["k"][tables[r, L // ps], :, L % ps]
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-6)
 
@@ -216,7 +219,7 @@ def test_attention_decode_paged_matches_contiguous():
 def test_attention_prefill_paged_writes_match_contiguous():
     """Paged prefill scatters the prompt K/V straight into pool pages:
     same attention output as the contiguous cache, and every logical
-    slot lands at pool[table[t // ps], t % ps] of the row's own table."""
+    slot lands at pool[table[t // ps], :, t % ps] of the row's own table."""
     b, ps, npages_seq, kvh, h, dh, d, s = 2, 4, 3, 2, 4, 16, 64, 10
     key = jax.random.PRNGKey(0)
     p = attention_init(key, d, h, kvh, dh)
@@ -228,7 +231,7 @@ def test_attention_prefill_paged_writes_match_contiguous():
                                   head_dim=dh)
 
     tables = jnp.asarray([[3, 1, 5], [2, 6, 4]], jnp.int32)
-    pool = {"k": jnp.zeros((7, ps, kvh, dh)), "v": jnp.zeros((7, ps, kvh, dh))}
+    pool = {"k": jnp.zeros((7, kvh, ps, dh)), "v": jnp.zeros((7, kvh, ps, dh))}
     out_p, cp = attention_prefill(p, x, pool, num_heads=h, kv_heads=kvh,
                                   head_dim=dh, page_table=tables)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_c),
@@ -236,10 +239,10 @@ def test_attention_prefill_paged_writes_match_contiguous():
     for r in range(b):
         for t in range(s):
             np.testing.assert_allclose(
-                np.asarray(cp["k"][tables[r, t // ps], t % ps]),
+                np.asarray(cp["k"][tables[r, t // ps], :, t % ps]),
                 np.asarray(cc["k"][r, t]), atol=1e-6)
             np.testing.assert_allclose(
-                np.asarray(cp["v"][tables[r, t // ps], t % ps]),
+                np.asarray(cp["v"][tables[r, t // ps], :, t % ps]),
                 np.asarray(cc["v"][r, t]), atol=1e-6)
 
 
@@ -694,3 +697,33 @@ def test_engine_steady_state_zero_recompiles_one_sync_per_chunk(kind):
     assert after["sync_regions"]["admission"] - \
         before["sync_regions"]["admission"] == 6
     assert all(r.status.name == "FINISHED" for r in eng.requests.values())
+
+
+@pytest.mark.parametrize("kind", ["dense", "packed"])
+def test_engine_prefill_logits_and_lowered_chunk(kind):
+    """``prefill_logits`` runs the admission prefill itself: its
+    first-token logits match ``lm_forward`` on the same params, and the
+    pages it borrows go back to the pool.  ``lower_decode_chunk`` lowers
+    the chunk ``step()`` runs without touching the engine's state, and
+    the engine then serves normally."""
+    cfg, dense_p, packed_p = _smoke_pair()
+    params = dense_p if kind == "dense" else packed_p
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
+                        max_seq_len=16, ticks_per_sync=2)
+    free0 = eng.pool.free_pages
+    prompt = np.random.default_rng(3).integers(
+        0, cfg.vocab, size=9).astype(np.int32)
+    got = eng.prefill_logits(prompt)
+    want, _ = lm_forward(params, {"tokens": jnp.asarray(prompt[None])}, cfg)
+    np.testing.assert_allclose(got, np.asarray(want[0, -1]),
+                               atol=1e-3, rtol=1e-4)
+    assert eng.pool.free_pages == free0
+
+    caches = eng.caches
+    lowered = eng.lower_decode_chunk()
+    toks_info = jax.tree.leaves(lowered.out_info)[0]
+    assert toks_info.shape == (2, 2)            # (ticks, slots)
+    assert eng.caches is caches and eng.tick == 0
+    eng.submit(prompt, 3)
+    done = eng.run()
+    np.testing.assert_array_equal(done[0].tokens, _solo(cfg, params, prompt, 3))

@@ -19,7 +19,7 @@ _SCRIPT = textwrap.dedent("""
 
     from repro.configs import SHAPES, get_config, input_specs, make_smoke
     from repro.configs.base import ShapeCell
-    from repro.distributed.sharding import axis_rules, cost_analysis, use_mesh
+    from repro.distributed.sharding import axis_rules
     from repro.launch.mesh import make_test_mesh
     from repro.launch.specs import cell_shardings, rules_for_cell, tree_named
     from repro.models.transformer import init_params
@@ -39,14 +39,14 @@ _SCRIPT = textwrap.dedent("""
         lambda: init_train_state(init_params(jax.random.PRNGKey(0), cfg), opt_cfg))
     sh = cell_shardings(cfg, cell, mesh, False, specs, state_shapes=state_shapes)
     rules = rules_for_cell(cell, mesh, False)
-    with use_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         step = make_train_step(cfg, opt_cfg, constant_lr(1e-3))
         fn = jax.jit(step,
                      in_shardings=(tree_named(sh["state"], mesh),
                                    tree_named(sh["batch"], mesh)),
                      out_shardings=(tree_named(sh["state"], mesh), None))
         compiled = fn.lower(state_shapes, specs["batch"]).compile()
-        ca = cost_analysis(compiled)   # shim normalizes pre-0.5 list form
+        ca = compiled.cost_analysis()
         assert ca["flops"] > 0
 
         # decode cell too
@@ -90,7 +90,7 @@ _MOE_EQUIV = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.distributed.sharding import axis_rules, make_train_rules, use_mesh
+    from repro.distributed.sharding import axis_rules, make_train_rules
     from repro.launch.mesh import make_test_mesh
     from repro.models.moe import moe_apply, moe_init
     from repro.models.moe_alltoall import moe_alltoall_apply
@@ -101,7 +101,7 @@ _MOE_EQUIV = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, D))
     kw = dict(num_experts=E, top_k=K, capacity_factor=8.0)  # no drops
 
-    with use_mesh(mesh), axis_rules(make_train_rules(False)):
+    with jax.set_mesh(mesh), axis_rules(make_train_rules(False)):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         ps = jax.tree.map(lambda a: jax.device_put(a), p)
         y_ref, aux_ref = jax.jit(lambda pp, xx: moe_apply(pp, xx, **kw))(ps, xs)
@@ -134,7 +134,7 @@ _MOE_A2A_PACKED = textwrap.dedent("""
     from repro.core import (BlockingSpec, apply_masks, build_structures,
                             masks_from_knapsack)
     from repro.core.packing import BSRPlanes
-    from repro.distributed.sharding import axis_rules, make_train_rules, use_mesh
+    from repro.distributed.sharding import axis_rules, make_train_rules
     from repro.launch.mesh import make_test_mesh
     from repro.models.moe import moe_apply, moe_init
     from repro.models.moe_alltoall import moe_alltoall_apply
@@ -156,7 +156,7 @@ _MOE_A2A_PACKED = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, D))
     kw = dict(num_experts=E, top_k=K, capacity_factor=8.0)  # no drops
 
-    with use_mesh(mesh), axis_rules(make_train_rules(False)):
+    with jax.set_mesh(mesh), axis_rules(make_train_rules(False)):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         # masked-dense GSPMD path is the oracle; the packed tree runs the
         # fused zero-skipping expert FFN behind the all_to_all dispatch

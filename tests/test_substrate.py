@@ -135,23 +135,52 @@ def test_trainer_straggler_detection():
 def test_compression_error_feedback_converges():
     """Accumulated int8 psum with error feedback is unbiased over steps."""
     import os
-    from repro.distributed.sharding import make_mesh, shard_map, use_mesh
+    from repro.distributed.sharding import make_mesh
     from repro.optim.compression import compressed_psum
 
     # single-device: emulate via shard_map on a 1-axis mesh of size 1
-    # (make_mesh/use_mesh/shard_map gate the post-0.4.x jax APIs)
     mesh = make_mesh((1,), ("pod",))
     from jax.sharding import PartitionSpec as P
 
     g = jnp.asarray(np.random.default_rng(0).normal(size=(64,)).astype(np.float32))
     err = jnp.zeros_like(g)
     total = jnp.zeros_like(g)
-    with use_mesh(mesh):
-        fn = shard_map(
+    with jax.set_mesh(mesh):
+        fn = jax.shard_map(
             lambda a, b: compressed_psum(a, b, "pod"),
-            mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check=False)
+            mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+            check_vma=False)
         for _ in range(50):
             out, err = fn(g, err)
             total = total + out
     # mean of 50 compressed reductions ~= g (error feedback cancels bias)
     np.testing.assert_allclose(np.asarray(total / 50), np.asarray(g), atol=1e-3)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set and no other path is
+    used; otherwise the cache goes to one fixed, git-ignored directory at
+    the root of the checkout."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                            enable_compile_cache)
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    if from_env:
+        assert path == str(tmp_path)
+        return
+    root = Path(__file__).resolve().parents[1]
+    assert path == str(CHECKOUT_CACHE_DIR) == str(root / ".jax_cache")
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
