@@ -226,11 +226,13 @@ def sync_region(tag: str) -> Iterator[None]:
     Counted per tag; inside the region host pulls are allowed (and
     counted when a meter is active).  Layered transfer-guard `allow`
     covers accelerator backends where the guard actually enforces.
+    The region is a ``repro.sync.<tag>`` span on the profiler's trace.
     """
     _region_counts[tag] = _region_counts.get(tag, 0) + 1
     _region_stack.append(tag)
     try:
-        with jax.transfer_guard_device_to_host("allow"):
+        with (jax.profiler.TraceAnnotation(f"repro.sync.{tag}"),
+              jax.transfer_guard_device_to_host("allow")):
             yield
     finally:
         _region_stack.pop()
@@ -274,10 +276,6 @@ def measure_pulls() -> Iterator[Dict[str, int]]:
 
 def region_counts() -> Dict[str, int]:
     return dict(_region_counts)
-
-
-def pull_counts() -> Dict[str, int]:
-    return dict(_pull_counts)
 
 
 def reset_counters() -> None:

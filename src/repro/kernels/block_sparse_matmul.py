@@ -166,6 +166,7 @@ def bsr_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_tiles * bm, grid_n * bn), jnp.float32),
         interpret=interpret,
+        name="bsr_matmul",
         **kwargs,
     )(bsr.indices, bsr.slots, *operands)
     return out[:m, :n].astype(x.dtype)
@@ -281,6 +282,7 @@ def bsr_planes_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct(
             (e, m_tiles * bm, grid_n * bn), jnp.float32),
         interpret=interpret,
+        name="bsr_planes_matmul",
         **kwargs,
     )(planes.indices, planes.slots, *operands)
     return out[:, :m, :n].astype(x.dtype)

@@ -276,6 +276,7 @@ def paged_attention_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
         interpret=interpret,
+        name="paged_attention_decode",
         **kwargs,
     )(page_table, clen, qg, kn, vn, k_pool, v_pool)
     return out.reshape(b, h, dh)
@@ -467,6 +468,7 @@ def paged_attention_prefill_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, s_pad * g, dh), jnp.float32),
         interpret=interpret,
+        name="paged_attention_prefill",
         **kwargs,
     )(page_table, ln, qt, k_pool, v_pool)
     out = out.reshape(b, kvh, s_pad, g, dh)[:, :, :s]

@@ -88,6 +88,7 @@ from typing import Dict, List, Optional, Sequence, Set
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import runtime as analysis_runtime
 from repro.configs.base import ModelConfig
@@ -529,59 +530,62 @@ class ServingEngine:
                     and need > self.pool.free_pages):
                 self.prefix_index.evict(need - self.pool.free_pages,
                                         exclude=pins | set(hits))
-            try:
-                if self.injector is not None:
-                    self.injector.on_alloc(self, need)
-                fresh = self.pool.alloc_pages(need)
-            except RuntimeError:
-                # allocator failure (injected or real): nothing of this
-                # request is committed yet — requeue it and the rest of
-                # the batch in order and retry at a later boundary
-                self.alloc_failures += 1
-                self._step_progress = True
-                self.scheduler.requeue(admitted[j:])
-                break
-            free.pop(0)
-            self.pool.share(hits)                 # map, don't recompute
-            pages = hits + fresh
-            self._tables[slot] = NULL_PAGE
-            self._tables[slot, :total] = pages
             # prefill only the uncached tail; the match is capped one
             # token short of the prompt, so the tail is never empty and
             # every write lands past the shared region
             start = n_hit * self.pool.page_size
-            first, ok, _, self.caches = _paged_prefill_step(
-                self.params, jnp.asarray(req.prompt[start:][None]),
-                self.caches, jnp.asarray(self._tables[slot][None]),
-                jnp.asarray(slot, jnp.int32), cfg=self.cfg, start=start,
-                guard=self.nan_guard)
-            # ONE declared host round-trip per admission: first token,
-            # guard verdict, and the request's decode key in a single
-            # batched pull (was three separate syncs)
-            with analysis_runtime.sync_region("admission"):
-                self.sync_regions["admission"] += 1
-                first_np, ok_np, rng_np = jax.device_get(
-                    (first, ok,
-                     jax.random.fold_in(self._base_key, req.rid)))
-            if self.nan_guard and not bool(ok_np):
-                # poisoned prefill: quarantine before the request ever
-                # holds a slot — its pages (and any cached blocks that
-                # fed them) must never be mapped again
-                self.guard_trips += 1
-                self.failed += 1
-                self._step_progress = True
-                req.tokens = np.zeros((0,), np.int32)
-                if self.prefix_index is not None:
-                    self.prefix_index.drop_pages(pages)
+            with TraceAnnotation("repro.prefill", rid=req.rid,
+                                 tokens=req.prompt_len - start,
+                                 hit_pages=n_hit):
+                try:
+                    if self.injector is not None:
+                        self.injector.on_alloc(self, need)
+                    fresh = self.pool.alloc_pages(need)
+                except RuntimeError:
+                    # allocator failure (injected or real): nothing of this
+                    # request is committed yet — requeue it and the rest of
+                    # the batch in order and retry at a later boundary
+                    self.alloc_failures += 1
+                    self._step_progress = True
+                    self.scheduler.requeue(admitted[j:])
+                    break
+                free.pop(0)
+                self.pool.share(hits)                 # map, don't recompute
+                pages = hits + fresh
                 self._tables[slot] = NULL_PAGE
-                self.scheduler.retire(
-                    req, pages, self.tick, status=RequestStatus.FAILED,
-                    reason="non-finite prefill logits (quarantined)")
-                free.insert(0, slot)
-                continue
-            self._cache_len[slot] = req.prompt_len
-            tok = int(first_np[0])
-            req.first_token_time = time.perf_counter()
+                self._tables[slot, :total] = pages
+                first, ok, _, self.caches = _paged_prefill_step(
+                    self.params, jnp.asarray(req.prompt[start:][None]),
+                    self.caches, jnp.asarray(self._tables[slot][None]),
+                    jnp.asarray(slot, jnp.int32), cfg=self.cfg, start=start,
+                    guard=self.nan_guard)
+                # ONE declared host round-trip per admission: first token,
+                # guard verdict, and the request's decode key in a single
+                # batched pull (was three separate syncs)
+                with analysis_runtime.sync_region("admission"):
+                    self.sync_regions["admission"] += 1
+                    first_np, ok_np, rng_np = jax.device_get(
+                        (first, ok,
+                         jax.random.fold_in(self._base_key, req.rid)))
+                if self.nan_guard and not bool(ok_np):
+                    # poisoned prefill: quarantine before the request ever
+                    # holds a slot — its pages (and any cached blocks that
+                    # fed them) must never be mapped again
+                    self.guard_trips += 1
+                    self.failed += 1
+                    self._step_progress = True
+                    req.tokens = np.zeros((0,), np.int32)
+                    if self.prefix_index is not None:
+                        self.prefix_index.drop_pages(pages)
+                    self._tables[slot] = NULL_PAGE
+                    self.scheduler.retire(
+                        req, pages, self.tick, status=RequestStatus.FAILED,
+                        reason="non-finite prefill logits (quarantined)")
+                    free.insert(0, slot)
+                    continue
+                self._cache_len[slot] = req.prompt_len
+                tok = int(first_np[0])
+                req.first_token_time = time.perf_counter()
             req.prefix_hit_pages = n_hit
             if self.prefix_index is not None:
                 self.prefix_index.insert(req.prompt, pages)
@@ -885,20 +889,36 @@ class ServingEngine:
         """One scheduler event: fault/lifecycle servicing, admission,
         then ONE on-device chunk of ``ticks_per_sync`` decode steps
         (or the adaptive policy's pick, see ``_next_ticks``).
-        Returns the number of requests admitted this event."""
+        Returns the number of requests admitted this event.
+
+        Each phase is a ``repro.*`` span on the profiler's trace (a
+        ``jax.profiler.TraceAnnotation``, free unless a profiler is
+        recording): ``repro.step`` around all of it, then
+        ``repro.verify_index``, ``repro.admit`` (one ``repro.prefill``
+        per admitted request), ``repro.cow_guard``, ``repro.dispatch``
+        (host->device copies and the chunk's enqueue),
+        ``repro.sync.decode_chunk`` (``sync_region``) and
+        ``repro.commit``."""
+        with TraceAnnotation("repro.step", tick=self.tick):
+            return self._step()
+
+    def _step(self) -> int:
         self._step_progress = False
         if self.injector is not None:
             self.injector.on_step_start(self)
-        self._verify_index()
+        with TraceAnnotation("repro.verify_index"):
+            self._verify_index()
         self._service_cancels()
         self._service_deadlines()
-        admitted = self._admit()
+        with TraceAnnotation("repro.admit", free=self.slots.count(None)):
+            admitted = self._admit()
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             self.tick += 1
             return admitted
         ticks = self._next_ticks(active)
-        self._cow_guard(active, ticks)
+        with TraceAnnotation("repro.cow_guard"):
+            self._cow_guard(active, ticks)
         left = np.zeros((self.num_slots,), np.int32)
         for i in active:
             left[i] = self.slots[i].req.max_new - len(self.slots[i].emitted)
@@ -906,9 +926,11 @@ class ServingEngine:
         try:
             if self.injector is not None:
                 self.injector.on_chunk_start(self, active, ticks)
-            args, static = self._chunk_call(left, ticks)
-            toks, counts, bad, tok, clen, rngs, caches = _decode_chunk(
-                *args, **static)
+            with TraceAnnotation("repro.dispatch", ticks=ticks,
+                                 active=len(active)):
+                args, static = self._chunk_call(left, ticks)
+                toks, counts, bad, tok, clen, rngs, caches = _decode_chunk(
+                    *args, **static)
         except Exception as err:
             self._recover_chunk_failure(snap, err)
             self.tick += 1
@@ -921,25 +943,26 @@ class ServingEngine:
             self.sync_regions["decode_chunk"] += 1
             toks, counts, bad, tok, clen, rngs = jax.device_get(
                 (toks, counts, bad, tok, clen, rngs))
-        self._tok = np.array(tok)
-        self._cache_len = np.array(clen)
-        self._rngs = np.array(rngs)
-        for i in active:
-            self.slots[i].emitted.extend(
-                int(t) for t in toks[:int(counts[i]), i])
-            if bad[i]:
-                self.guard_trips += 1
-                self.failed += 1
-                self._step_progress = True
-                self._release_slot(
-                    i, RequestStatus.FAILED,
-                    reason="non-finite decode logits (quarantined)")
-            else:
-                self._maybe_finish(i)
-        self.active_slot_ticks += int(counts.sum())
-        self.decode_ticks += ticks
-        self.tick += ticks
-        self._count_chunk(ticks)
+        with TraceAnnotation("repro.commit"):
+            self._tok = np.array(tok)
+            self._cache_len = np.array(clen)
+            self._rngs = np.array(rngs)
+            for i in active:
+                self.slots[i].emitted.extend(
+                    int(t) for t in toks[:int(counts[i]), i])
+                if bad[i]:
+                    self.guard_trips += 1
+                    self.failed += 1
+                    self._step_progress = True
+                    self._release_slot(
+                        i, RequestStatus.FAILED,
+                        reason="non-finite decode logits (quarantined)")
+                else:
+                    self._maybe_finish(i)
+            self.active_slot_ticks += int(counts.sum())
+            self.decode_ticks += ticks
+            self.tick += ticks
+            self._count_chunk(ticks)
         return admitted
 
     @property

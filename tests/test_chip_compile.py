@@ -12,6 +12,7 @@ only one process may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -176,3 +177,64 @@ def test_bsr_planes_matmul_compiles_for_v5e(one_chip, m):
                    activation="silu")
     _compile(lambda x, w, ep: bsr_planes_matmul_pallas(x, w, epilogue=ep),
              x, planes, epi)
+
+
+def _named_kernels(hlo: str, kernel: str) -> int:
+    """Instructions of ``hlo`` that are the Pallas kernel ``kernel``, in
+    the form the device trace names an operation and ``bench/trace.py``'s
+    ``kernel_time`` matches it: ``%<kernel>.N = ... tpu_custom_call``."""
+    rx = re.compile(rf'^%{kernel}(\.\d+)? = .*'
+                    r'custom_call_target="tpu_custom_call"')
+    return sum(bool(rx.match(line.strip().removeprefix("ROOT ")))
+               for line in hlo.splitlines())
+
+
+def _kernel_call(kernel, sharding):
+    """(body, carry, rest) of one call of ``kernel``'s ``pallas_call``,
+    with no jit of its own around it; the output is shaped like the
+    carry."""
+    if kernel == "paged_attention_decode":
+        b, h, dh, ps, mp = 8, 16, 64, 16, 16
+        pool = _sds((b * mp + 1, h, ps, dh), "float32", sharding)
+        rest = (_sds((b, h, dh), "bfloat16", sharding),
+                _sds((b, h, dh), "bfloat16", sharding), pool, pool,
+                _sds((b, mp), "int32", sharding), _sds((b,), "int32", sharding))
+        return (lambda q, *a: paged_attention_decode_pallas(
+            q, *a).astype(q.dtype),
+            _sds((b, h, dh), "bfloat16", sharding), rest)
+    if kernel == "paged_attention_prefill":
+        s, h, dh, ps, mp = 128, 16, 64, 16, 16
+        pool = _sds((mp + 1, h, ps, dh), "float32", sharding)
+        rest = (pool, pool, _sds((1, mp), "int32", sharding),
+                _sds((1,), "int32", sharding))
+        return (lambda q, *a: paged_attention_prefill_pallas(
+            q, *a).astype(q.dtype),
+            _sds((1, s, h, dh), "bfloat16", sharding), rest)
+    if kernel == "bsr_matmul":
+        return (bsr_matmul_pallas, _sds((8, 1024), "bfloat16", sharding),
+                (_bsr_spec(1024, 1024, sharding),))
+    e = 4
+    return (bsr_planes_matmul_pallas, _sds((e, 8, 512), "bfloat16", sharding),
+            (_bsr_spec(512, 512, sharding, planes=e),))
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention_decode",
+                                    "paged_attention_prefill",
+                                    "bsr_matmul", "bsr_planes_matmul"])
+def test_kernels_keep_their_names_in_the_compiled_program(one_chip, kernel):
+    """Each Pallas kernel compiles to a ``tpu_custom_call`` instruction
+    named by its ``pallas_call(name=...)``, not after whichever jit
+    encloses it.  The device trace names an operation by its instruction,
+    so this is the name the benchmark's kernel metrics find it by.  The
+    kernel is called with no jit of its own, inside a scan in an outer
+    jit: without ``name=`` its instruction takes the outer jit's name."""
+    body, carry, rest = _kernel_call(kernel, one_chip)
+
+    def chunk(x, *a):
+        return jax.lax.scan(lambda c, _: (body(c, *a), None), x, None,
+                            length=2)[0]
+
+    hlo = jax.jit(chunk).lower(carry, *rest).compile().as_text()
+    assert _named_kernels(hlo, kernel) >= 1, \
+        [ln.strip()[:100] for ln in hlo.splitlines()
+         if "tpu_custom_call" in ln]

@@ -727,3 +727,75 @@ def test_engine_prefill_logits_and_lowered_chunk(kind):
     eng.submit(prompt, 3)
     done = eng.run()
     np.testing.assert_array_equal(done[0].tokens, _solo(cfg, params, prompt, 3))
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler's trace
+# ---------------------------------------------------------------------------
+
+def _traced_spans(fn, log_dir):
+    """``fn()`` under the JAX profiler; the ``repro.*`` host events it
+    left in the trace as (name, start, end, stats), by start."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans.extend((e.name, e.start_ns, e.end_ns, dict(e.stats))
+                         for e in line.events if e.name.startswith("repro."))
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.mark.parametrize("kind", ["dense", "packed"])
+def test_engine_step_spans_on_the_profiler_trace(kind, tmp_path):
+    """Every phase of ``step()`` is a ``repro.*`` span inside its
+    ``repro.step``: one ``repro.sync.decode_chunk`` per committed chunk,
+    one ``repro.dispatch`` before each, and one ``repro.prefill`` per
+    admission carrying its request id and tail length."""
+    cfg, dense_p, packed_p = _smoke_pair()
+    params = dense_p if kind == "dense" else packed_p
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
+                        max_seq_len=16, ticks_per_sync=2)
+    rng = np.random.default_rng(11)
+    lens = [5, 9, 7, 6]
+    rids = [eng.submit(rng.integers(0, cfg.vocab, size=n).astype(np.int32),
+                       4, arrival=2 * i) for i, n in enumerate(lens)]
+    spans = _traced_spans(eng.run, tmp_path)
+
+    steps = [s for s in spans if s[0] == "repro.step"]
+    inner = [s for s in spans if s[0] != "repro.step"]
+    assert steps and inner
+    assert [s[3]["tick"] for s in steps] == sorted(s[3]["tick"] for s in steps)
+    for name, a, b, _ in inner:
+        assert any(sa <= a and b <= sb for _, sa, sb, _ in steps), name
+
+    def named(n):
+        return [s for s in spans if s[0] == n]
+
+    chunks = sum(eng.chunks_by_ticks.values())
+    assert chunks >= 3
+    assert len(named("repro.sync.decode_chunk")) == chunks
+    assert len(named("repro.dispatch")) == chunks
+    assert len(named("repro.commit")) == chunks
+    assert all(d[3]["ticks"] == 2 and 1 <= d[3]["active"] <= 2
+               for d in named("repro.dispatch"))
+    assert len(named("repro.verify_index")) == len(steps)
+    assert len(named("repro.admit")) == len(steps)
+    prefills = named("repro.prefill")
+    assert [p[3]["rid"] for p in prefills] == rids
+    assert [p[3]["tokens"] for p in prefills] == lens
+    assert all(p[3]["hit_pages"] == 0 for p in prefills)
+    assert len(named("repro.sync.admission")) == len(rids)
+    # each admission's round trip lies inside its own prefill
+    for p, s in zip(prefills, named("repro.sync.admission")):
+        assert p[1] <= s[1] and s[2] <= p[2]
