@@ -2,21 +2,29 @@
 
 The serving pool stores every sequence's KV in fixed-size pages
 ``(num_pages, K, page_size, dh)`` with a per-row table of page ids
-(serving/pages.py, DESIGN.md §9).  Each (page, kv head) is one
-contiguous ``(page_size, dh)`` tile, so a kernel block covers whole
-trailing dims the TPU's Mosaic compiler can tile: ``page_size`` must be
-a multiple of the pool dtype's sublane count (8 for fp32, 16 for bf16).
-The naive decode path materializes the logical view first — a gather of
-``pool[page_table]`` to ``(b, max_len, K, dh)`` — so every token pays
-O(max_pages · page_size) memory traffic no matter how short the row's
-real context is.  These kernels instead *walk* the
-table: grid over (batch, kv_head), inner loop over pages, an
-online-softmax accumulator carried across pages, and the just-computed
-current token's K/V kept in-register (it seeds the accumulator and never
-round-trips through the pool).  Work and traffic scale with the live
-``cache_len``, not the allocation — the same locality argument the
-paper makes for structured pruning: compression only pays when the
-kernel respects the memory layout.
+(serving/pages.py, DESIGN.md §9).  The pool is page-major, so one page
+across all KV heads is one contiguous ``(1, K, page_size, dh)`` block
+whose trailing dims the TPU's Mosaic compiler can tile: ``page_size``
+must be a multiple of the pool dtype's sublane count (8 for fp32, 16
+for bf16).  The naive decode path materializes the logical view first —
+a gather of ``pool[page_table]`` to ``(b, max_len, K, dh)`` — so every
+token pays O(max_pages · page_size) memory traffic no matter how short
+the row's real context is.  These kernels instead *walk* the table with
+an online-softmax accumulator carried across pages, and the
+just-computed current token's K/V kept in-register (it seeds the
+accumulator and never round-trips through the pool).  HBM traffic
+scales with the live ``cache_len``, not the allocation — the same
+locality argument the paper makes for structured pruning: compression
+only pays when the kernel respects the memory layout.
+
+Decode's grid is (batch, page block): one step covers one slot, all of
+its KV heads and ``pages_per_step`` consecutive logical pages, scored as
+a per-head batched contraction.  ``pages_per_step`` is derived from the
+shapes (:func:`decode_pages_per_step`).  The grid is statically sized
+by the table width, so the *step count* scales with ``max_pages``, not
+with ``cache_len``: a slot with a short context still walks every block,
+and its dead blocks pay the fixed per-step cost without their DMA.
+Prefill's grid is (batch, kv_head, query tile, page).
 
 Online-softmax recurrence per page (all fp32):
 
@@ -36,13 +44,14 @@ Two backends behind ``ops.paged_attention_decode`` / ``_prefill``:
 * ``*_ref``    — pure-jnp, but still **non-gathering**: a
   ``fori_loop`` over page *segments* bounded by ``max(cache_len)``, so
   CPU serving gets the same work-scales-with-context contract as the
-  TPU kernel (and stays bit-comparable to it at ``pages_per_step=1`` —
-  the ref mirrors the kernel's op sequence exactly).
+  TPU kernel (and stays bit-comparable to it at the same
+  ``pages_per_step`` — the ref mirrors the kernel's op sequence).
 * ``*_pallas`` — the TPU kernel; ``interpret=True`` runs the same body
-  on CPU for CI.  Page ids are scalar-prefetched (SMEM) and the pool
-  BlockSpec index map clamps dead steps to the last live page, so a
-  revisited block index skips the DMA — traffic is O(cache_len) even
-  though the grid is statically sized by the table width.
+  on CPU for CI.  Page ids are scalar-prefetched (SMEM) and each pool
+  BlockSpec index map clamps page slots past the live context to the
+  last live page, so a revisited block index skips the DMA — traffic is
+  O(cache_len) even though the grid is statically sized by the table
+  width.
 
 Masked positions never touch values: scores get the finite ``NEG_INF``
 sentinel *and* the value contribution is zeroed (``p`` is where-masked),
@@ -60,6 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "decode_pages_per_step",
     "paged_attention_decode_ref",
     "paged_attention_decode_pallas",
     "paged_attention_prefill_ref",
@@ -67,6 +77,7 @@ __all__ = [
 ]
 
 NEG_INF = -1e30  # finite mask sentinel (matches models/attention.py)
+DECODE_VMEM_BUDGET = 4 * 1024 * 1024  # double-buffered K+V page blocks
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -83,7 +94,7 @@ def _segment(pool: jnp.ndarray, pid: jnp.ndarray) -> jnp.ndarray:
 
 
 def _check_page_tiling(pool: jnp.ndarray) -> None:
-    """A (1, 1, page_size, dh) pool block is tiled by Mosaic only when
+    """A (1, ·, page_size, dh) pool block is tiled by Mosaic only when
     page_size fills whole sublane tiles of the pool dtype."""
     sublanes = 8 * 4 // pool.dtype.itemsize
     if pool.shape[2] % sublanes:
@@ -109,9 +120,9 @@ def paged_attention_decode_ref(
 ) -> jnp.ndarray:
     """Non-gathering reference: page-segment ``fori_loop`` bounded by
     ``max(cache_len)``, online softmax across segments.  Returns
-    (B, H, dh) fp32.  ``pages_per_step=1`` is bit-comparable to the
-    Pallas kernel (same op order per page); larger segments amortize the
-    loop on CPU and stay within float rounding of it."""
+    (B, H, dh) fp32.  At the kernel's ``pages_per_step`` it is
+    bit-comparable to the Pallas kernel (same op order per block); other
+    widths stay within float rounding of it."""
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
@@ -166,53 +177,74 @@ def paged_attention_decode_ref(
     return (acc / l).reshape(b, h, dh)
 
 
-def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
-                   scale: float):
-    """Grid (B, K, max_pages); scratch m/l/acc persists across the
-    innermost page dimension.  j == 0 seeds from the in-register current
-    token; dead pages (j·ps >= cache_len) are skipped; the last step
+def decode_pages_per_step(kv_heads: int, page_size: int, head_dim: int,
+                          dtype, max_pages: int) -> int:
+    """Pages one decode grid step fetches: the largest power of two
+    <= ``max_pages`` whose double-buffered K and V blocks — four
+    ``(pages, K, page_size, dh)`` buffers, lanes padded to 128 — fit
+    ``DECODE_VMEM_BUDGET``.  Derived from the shapes, never configured."""
+    page = (kv_heads * page_size * _cdiv(head_dim, 128) * 128
+            * jnp.dtype(dtype).itemsize)
+    pps = 1
+    while pps * 2 <= max_pages and 4 * pps * 2 * page <= DECODE_VMEM_BUDGET:
+        pps *= 2
+    return pps
+
+
+def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, *refs,
+                   page_size: int, pages_per_step: int, scale: float):
+    """Grid (B, page blocks); one step covers one slot, all its KV heads
+    and ``pages_per_step`` logical pages, each page one ``(1, K, ps, dh)``
+    pool block of its own.  Scratch m/l/acc (K, G, ·) persists across
+    the block dimension: j == 0 seeds from the in-register current
+    token, blocks past the live context are skipped, the last step
     normalizes into the output block."""
+    pps = pages_per_step
+    k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
     bb = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     clen = clen_ref[bb]
-    qg = q_ref[0, 0].astype(jnp.float32)                    # (G, dh)
+    seg = pps * page_size
+    qg = q_ref[0].astype(jnp.float32)                       # (K, G, dh)
 
     @pl.when(j == 0)
     def _seed():
-        kn = kn_ref[0, 0].astype(jnp.float32)               # (1, dh)
+        kn = kn_ref[0].astype(jnp.float32)                  # (K, 1, dh)
         s_new = jnp.sum(qg * kn, axis=-1, keepdims=True) * scale
-        m_ref[...] = s_new                                  # (G, 1)
+        m_ref[...] = s_new                                  # (K, G, 1)
         l_ref[...] = jnp.ones_like(s_new)
         acc_ref[...] = jnp.broadcast_to(
-            vn_ref[0, 0].astype(jnp.float32), acc_ref.shape)
+            vn_ref[0].astype(jnp.float32), acc_ref.shape)
 
-    @pl.when(j * page_size < clen)
-    def _page():
-        kp = kp_ref[0, 0].astype(jnp.float32)               # (ps, dh)
-        vp = vp_ref[0, 0].astype(jnp.float32)
+    @pl.when(j * seg < clen)
+    def _block():
+        kp = jnp.concatenate([r[0].astype(jnp.float32) for r in k_refs],
+                             axis=1)                        # (K, seg, dh)
+        vp = jnp.concatenate([r[0].astype(jnp.float32) for r in v_refs],
+                             axis=1)
         s = jax.lax.dot_general(
-            qg, kp, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (G, ps)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < clen                                  # (1, ps)
+            qg, kp, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale     # (K, G, seg)
+        pos = j * seg + jax.lax.broadcasted_iota(jnp.int32, (1, 1, seg), 2)
+        valid = pos < clen
         s = jnp.where(valid, s, NEG_INF)
-        kv_live = (j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < clen
+        kv_live = (j * seg + jax.lax.broadcasted_iota(
+            jnp.int32, (1, seg, 1), 1)) < clen
         vp = jnp.where(kv_live, vp, 0.0)
         m = m_ref[...]
         m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         r = jnp.exp(m - m2)
         p = jnp.where(valid, jnp.exp(s - m2), 0.0)
         l_ref[...] = l_ref[...] * r + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * r + jnp.dot(
-            p, vp, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * r + jax.lax.dot_general(
+            p, vp, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m2
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        o_ref[0, 0] = acc_ref[...] / l_ref[...]
+        o_ref[0] = acc_ref[...] / l_ref[...]
 
 
 def paged_attention_decode_pallas(
@@ -224,8 +256,11 @@ def paged_attention_decode_pallas(
     page_table: jnp.ndarray,   # (B, max_pages) int32
     cache_len: jnp.ndarray,    # (B,) int32
     *,
+    pages_per_step: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """Decode over the page walk; ``pages_per_step`` defaults to
+    :func:`decode_pages_per_step` of the shapes (tests pin it)."""
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
@@ -234,6 +269,8 @@ def paged_attention_decode_pallas(
     scale = 1.0 / math.sqrt(dh)
     if not interpret:
         _check_page_tiling(k_pool)
+    pps = pages_per_step or decode_pages_per_step(
+        kvh, ps, dh, k_pool.dtype, max_pages)
     qg = q.reshape(b, kvh, g, dh)
     # a unit sublane dim keeps every block's trailing dims whole
     kn = k_new.reshape(b, kvh, 1, dh)
@@ -241,44 +278,49 @@ def paged_attention_decode_pallas(
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
 
-    def pool_map(bb, k, j, tbl, cl):
-        # clamp dead steps to the last live page: a repeated block index
-        # skips the DMA, so traffic is O(cache_len) not O(max_pages)
-        live = (cl[bb] + ps - 1) // ps
-        jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
-        return (tbl[bb, jj], k, 0, 0)
+    def page_map(i):
+        def index(bb, j, tbl, cl):
+            # clamp pages past the live context to the last live page: a
+            # repeated block index skips the DMA, so traffic is
+            # O(cache_len) though the grid walks every block
+            live = (cl[bb] + ps - 1) // ps
+            jj = jnp.minimum(j * pps + i, jnp.maximum(live - 1, 0))
+            return (tbl[bb, jj], 0, 0, 0)
+        return index
 
-    row_map = lambda bb, k, j, tbl, cl: (bb, k, 0, 0)  # noqa: E731
+    row_map = lambda bb, j, tbl, cl: (bb, 0, 0, 0)  # noqa: E731
+    pages = [pl.BlockSpec((1, kvh, ps, dh), page_map(i)) for i in range(pps)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, max_pages),
+        grid=(b, _cdiv(max_pages, pps)),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), row_map),
-            pl.BlockSpec((1, 1, 1, dh), row_map),
-            pl.BlockSpec((1, 1, 1, dh), row_map),
-            pl.BlockSpec((1, 1, ps, dh), pool_map),
-            pl.BlockSpec((1, 1, ps, dh), pool_map),
+            pl.BlockSpec((1, kvh, g, dh), row_map),
+            pl.BlockSpec((1, kvh, 1, dh), row_map),
+            pl.BlockSpec((1, kvh, 1, dh), row_map),
+            *pages,                               # K, one per page slot
+            *pages,                               # V
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), row_map),
+        out_specs=pl.BlockSpec((1, kvh, g, dh), row_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max m
-            pltpu.VMEM((g, 1), jnp.float32),     # running normalizer l
-            pltpu.VMEM((g, dh), jnp.float32),    # fp32 output accumulator
+            pltpu.VMEM((kvh, g, 1), jnp.float32),     # running max m
+            pltpu.VMEM((kvh, g, 1), jnp.float32),     # running normalizer l
+            pltpu.VMEM((kvh, g, dh), jnp.float32),    # fp32 accumulator
         ],
     )
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=ps, scale=scale),
+        functools.partial(_decode_kernel, page_size=ps, pages_per_step=pps,
+                          scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
         interpret=interpret,
         name="paged_attention_decode",
         **kwargs,
-    )(page_table, clen, qg, kn, vn, k_pool, v_pool)
+    )(page_table, clen, qg, kn, vn, *(k_pool,) * pps, *(v_pool,) * pps)
     return out.reshape(b, h, dh)
 
 

@@ -92,6 +92,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.analysis import runtime as analysis_runtime
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import decode_pages_per_step
 from repro.models import init_caches, layer_specs, lm_decode, lm_prefill
 from repro.models.transformer import _select_token_rows
 
@@ -401,6 +402,17 @@ class ServingEngine:
         self._next_rid = 0
         self.active_slot_ticks = 0
         self.decode_ticks = 0
+        # the TPU decode kernel's grid: per tick, every slot walks
+        # ceil(max_pages / pps) blocks of pps pages; only the pages its
+        # cache_len occupies are live.  Their ratio is the grid's share
+        # of real work (host counters from the cache_len mirror)
+        self.attn_live_page_ticks = 0
+        self.attn_walked_page_ticks = 0
+        pps = decode_pages_per_step(kvh, page_size, hd, jnp.float32,
+                                    self.max_pages)
+        self._attn_walk = (-(-self.max_pages // pps) * pps
+                           if any(spec.mixer == "attn"
+                                  for spec in self._specs) else 0)
         # declared host round-trips (analysis_stats / DESIGN.md §14):
         # one "decode_chunk" region per chunk, one "admission" region
         # per admitted request — everything else stays on device
@@ -841,6 +853,19 @@ class ServingEngine:
                 self.chunk_grows += 1
         self._last_chunk_ticks = ticks
 
+    def _count_attn_pages(self, ticks: int, counts: np.ndarray) -> None:
+        """Add a committed chunk to the attention page counters.  A
+        slot's context at tick ``t`` is its cache_len before the chunk
+        plus the tokens it had emitted by then (``min(t, counts)``: a
+        frozen row stays where it stopped)."""
+        if not self._attn_walk:
+            return
+        ps = self.pool.page_size
+        ctx = self._cache_len[None, :] + np.minimum(
+            np.arange(ticks)[:, None], np.asarray(counts)[None, :])
+        self.attn_live_page_ticks += int(((ctx + ps - 1) // ps).sum())
+        self.attn_walked_page_ticks += ticks * self.num_slots * self._attn_walk
+
     def _chunk_call(self, left: np.ndarray, ticks: int):
         """(args, static kwargs) of the ``_decode_chunk`` call for the
         current host mirrors and per-slot budgets ``left``."""
@@ -944,6 +969,7 @@ class ServingEngine:
             toks, counts, bad, tok, clen, rngs = jax.device_get(
                 (toks, counts, bad, tok, clen, rngs))
         with TraceAnnotation("repro.commit"):
+            self._count_attn_pages(ticks, counts)
             self._tok = np.array(tok)
             self._cache_len = np.array(clen)
             self._rngs = np.array(rngs)

@@ -73,12 +73,23 @@ ATTN_WIDTHS = [(16, 16), (16, 8)]
 POOLS = [(8, "float32"), (16, "float32"), (16, "bfloat16")]
 
 
-@pytest.mark.parametrize("ps,pool_dtype", POOLS)
-@pytest.mark.parametrize("h,kvh", ATTN_WIDTHS)
-def test_paged_decode_compiles_for_v5e(one_chip, h, kvh, ps, pool_dtype):
-    b, dh, max_seq = 8, 64, 1024
-    mp = max_seq // ps
-    n_pages = b * mp + 1
+def _decode_shapes():
+    """(h, kvh, ps, pool dtype, b, max_pages, pool pages): every width x
+    pool at 8 slots of 1024 context, and the decode cells' exact shape
+    (16 slots, 40-page tables over a 641-page pool)."""
+    for h, kvh in ATTN_WIDTHS:
+        for ps, pool_dtype in POOLS:
+            mp = 1024 // ps
+            yield pytest.param(h, kvh, ps, pool_dtype, 8, mp, 8 * mp + 1,
+                               id=f"{h}-{kvh}-{ps}-{pool_dtype}")
+    yield pytest.param(16, 16, 16, "float32", 16, 40, 641,
+                       id="decode-cells-b16-mp40-p641")
+
+
+@pytest.mark.parametrize("h,kvh,ps,pool_dtype,b,mp,n_pages", _decode_shapes())
+def test_paged_decode_compiles_for_v5e(one_chip, h, kvh, ps, pool_dtype, b,
+                                       mp, n_pages):
+    dh = 64
     args = (
         _sds((b, h, dh), "bfloat16", one_chip),
         _sds((b, kvh, dh), "bfloat16", one_chip),

@@ -7,9 +7,10 @@ the ref against a dense gather oracle, and the ``attention_decode`` /
 across ragged ``(B,)`` cache_len (including empty rows parked on the
 null page), GQA ratios, and page sizes 4/8/16.
 
-The ref mirrors the kernel's op sequence exactly (same seed, same
-per-page update order), so kernel-vs-ref agreement is at float32
-rounding (1–2 ulp from einsum batching), not accumulated drift.
+The ref mirrors the kernel's op sequence exactly at the same
+``pages_per_step`` (same seed, same per-block update order), so
+kernel-vs-ref agreement is at float32 rounding (1–2 ulp from einsum
+batching), not accumulated drift.
 """
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,8 @@ import pytest
 
 from repro.kernels import paged_attention_decode, paged_attention_prefill
 from repro.kernels.paged_attention import (
+    DECODE_VMEM_BUDGET,
+    decode_pages_per_step,
     paged_attention_decode_pallas,
     paged_attention_decode_ref,
     paged_attention_prefill_pallas,
@@ -78,20 +81,43 @@ def _decode_oracle(q, kn, vn, kp, vp, tbl, clen):
     return np.einsum("bkgs,bskd->bkgd", w, cv).reshape(b, h, dh)
 
 
-@pytest.mark.parametrize("ps", [4, 8, 16])
-@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2), (4, 1)])
-def test_paged_decode_kernel_interpret_matches_ref(ps, h, kvh):
-    """Decode-grid (M=1) kernel body under the interpreter vs the
-    page-per-step ref: same op order, float-rounding agreement, across
-    ragged cache_len including an empty row on the null page."""
+def _decode_cases():
+    """(h, kvh, ps, pages_per_step, pool dtype): every pinned width and
+    the derived one (None) on fp32 pools, the derived width and 2 on
+    bf16 pools.  The derived-width fp32 cases keep their plain
+    ``h-kvh-ps`` ids."""
+    for h, kvh in [(4, 4), (8, 2), (4, 1)]:
+        for ps in (4, 8, 16):
+            for pps, dtype in [(None, "float32"), (1, "float32"),
+                               (2, "float32"), (4, "float32"),
+                               (None, "bfloat16"), (2, "bfloat16")]:
+                tag = ("" if pps is None and dtype == "float32"
+                       else f"-pps{pps or 'auto'}-{dtype}")
+                yield pytest.param(h, kvh, ps, pps, dtype,
+                                   id=f"{h}-{kvh}-{ps}{tag}")
+
+
+@pytest.mark.parametrize("h,kvh,ps,pps,pool_dtype", _decode_cases())
+def test_paged_decode_kernel_interpret_matches_ref(h, kvh, ps, pps,
+                                                   pool_dtype):
+    """Decode-grid kernel body under the interpreter vs the ref at the
+    same page-block width: same op order, float-rounding agreement,
+    across ragged cache_len including an empty row on the null page and
+    a full table (max_pages = 5 is no multiple of 2 or 4)."""
     rng = np.random.default_rng(ps * 10 + h)
     b, dh, mp = 4, 32, 5
     clens = [0, 1, ps * mp - 1, int(rng.integers(1, ps * mp - 1))]
-    args = _mk_decode(rng, b, h, kvh, dh, ps, mp, clens)
-    ref = paged_attention_decode_ref(*args, pages_per_step=1)
-    ker = paged_attention_decode_pallas(*args, interpret=True)
+    q, kn, vn, kp, vp, tbl, clen = _mk_decode(rng, b, h, kvh, dh, ps, mp,
+                                              clens)
+    kp, vp = kp.astype(pool_dtype), vp.astype(pool_dtype)
+    args = (q, kn, vn, kp, vp, tbl, clen)
+    width = pps or decode_pages_per_step(kvh, ps, dh, kp.dtype, mp)
+    ref = paged_attention_decode_ref(*args, pages_per_step=width)
+    ker = paged_attention_decode_pallas(*args, pages_per_step=pps,
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=ATOL)
-    orc = _decode_oracle(*args)
+    orc = _decode_oracle(q, kn, vn, kp.astype(jnp.float32),
+                         vp.astype(jnp.float32), tbl, clen)
     np.testing.assert_allclose(np.asarray(ref), orc, atol=1e-5)
 
 
@@ -110,7 +136,9 @@ def test_paged_decode_ref_segment_width_invariant():
 def test_paged_decode_never_reads_unallocated_pages():
     """NaN-poison every slot outside the live prefix of each row's own
     pages (including the whole null page): outputs must be finite and
-    bit-identical to the clean-pool run on ref AND interpret kernel."""
+    bit-identical to the clean-pool run on ref AND interpret kernel —
+    the kernel at its derived page-block width (all 4 pages) and at 2,
+    where a block's page slots past the context hold the last live page."""
     rng = np.random.default_rng(5)
     b, h, kvh, dh, ps, mp = 3, 8, 4, 32, 4, 4
     clens = [0, 5, 13]
@@ -120,10 +148,36 @@ def test_paged_decode_never_reads_unallocated_pages():
     clean = tuple(jnp.nan_to_num(a, nan=0.0) if a.ndim == 4 else a
                   for a in dirty)
     for fn in (lambda *a: paged_attention_decode_ref(*a, pages_per_step=2),
-               lambda *a: paged_attention_decode_pallas(*a, interpret=True)):
+               lambda *a: paged_attention_decode_pallas(*a, interpret=True),
+               lambda *a: paged_attention_decode_pallas(
+                   *a, pages_per_step=2, interpret=True)):
         got = np.asarray(fn(*dirty))
         assert np.isfinite(got).all()
         np.testing.assert_array_equal(got, np.asarray(fn(*clean)))
+
+
+@pytest.mark.parametrize("kvh,ps,dtype,mp,want", [
+    (16, 16, "float32", 40, 8),      # the decode cells: qwen1.5-0.5b
+    (16, 8, "float32", 128, 16),     # the described-v5e compile shapes
+    (16, 16, "float32", 64, 8),
+    (16, 16, "bfloat16", 64, 16),
+    (8, 8, "float32", 128, 32),
+    (8, 16, "float32", 64, 16),
+    (8, 16, "bfloat16", 64, 32),
+    (16, 16, "float32", 3, 2),       # capped by the table width
+    (64, 64, "float32", 40, 1),      # one page overfills the budget
+])
+def test_decode_pages_per_step_fits_its_vmem_budget(kvh, ps, dtype, mp,
+                                                    want):
+    """The derived page-block width is the largest power of two within
+    the table whose four double-buffered K/V blocks, lanes padded to
+    128, fit the budget (dh 64 pads to 128)."""
+    pps = decode_pages_per_step(kvh, ps, 64, dtype, mp)
+    assert pps == want
+    page = kvh * ps * 128 * jnp.dtype(dtype).itemsize
+    assert pps & (pps - 1) == 0 and 1 <= pps <= max(mp, 1)
+    assert pps == 1 or 4 * pps * page <= DECODE_VMEM_BUDGET
+    assert 2 * pps > mp or 4 * 2 * pps * page > DECODE_VMEM_BUDGET
 
 
 def test_paged_decode_ops_mode_dispatch():

@@ -729,6 +729,32 @@ def test_engine_prefill_logits_and_lowered_chunk(kind):
     np.testing.assert_array_equal(done[0].tokens, _solo(cfg, params, prompt, 3))
 
 
+def test_engine_attention_page_counters_hand_count():
+    """``attn_live_page_ticks`` sums, over committed ticks and slots, the
+    pages each slot's cache_len occupies; ``attn_walked_page_ticks``
+    counts the decode grid's page steps.  Page size 4, 2 slots, 2-tick
+    chunks.  A (prompt 5, 4 tokens) decodes 3 ticks at cache_len 5, 6,
+    7, then rides the rest of chunk 2 frozen at 8: 2 pages each tick.
+    B (prompt 3, 3 tokens) decodes 2 ticks at 3, 4 (1 page each) and
+    retires; its free slot walks 0 pages in chunk 2.  Live: 4·2 + 2·1."""
+    from repro.kernels.paged_attention import decode_pages_per_step
+
+    cfg, dense, _ = _smoke_pair()
+    rng = np.random.default_rng(13)
+    eng = ServingEngine(dense, cfg, num_slots=2, page_size=4,
+                        max_seq_len=16, ticks_per_sync=2)
+    eng.submit(rng.integers(0, cfg.vocab, size=5).astype(np.int32), 4)
+    eng.submit(rng.integers(0, cfg.vocab, size=3).astype(np.int32), 3)
+    eng.run()
+    assert eng.decode_ticks == 4
+    assert eng.attn_live_page_ticks == 10
+    pps = decode_pages_per_step(cfg.kv_heads, 4, cfg.head_dim_(),
+                                jnp.float32, eng.max_pages)
+    walk = -(-eng.max_pages // pps) * pps
+    assert eng.attn_walked_page_ticks == eng.decode_ticks * 2 * walk
+    assert eng.attn_live_page_ticks <= eng.attn_walked_page_ticks
+
+
 # ---------------------------------------------------------------------------
 # Program spans on the profiler's trace
 # ---------------------------------------------------------------------------
