@@ -116,7 +116,7 @@ def _map_body(fn) -> ast.AST:
 
 
 def _index_map_free_names(fn) -> Set[str]:
-    bound = set(_map_params(fn))
+    bound = set(_map_params(fn)) | {a.arg for a in fn.args.kwonlyargs}
     body = fn.body if isinstance(fn, ast.Lambda) else fn
     nodes = list(ast.walk(body if isinstance(body, ast.AST) else fn))
     # names assigned inside the map body are its locals, not captures
@@ -151,13 +151,18 @@ def _block_specs(node: ast.AST) -> List[ast.Call]:
 def _spec_parts(spec: ast.Call, local_defs: Dict[str, ast.FunctionDef]) -> Tuple[Optional[ast.AST], Optional[ast.AST]]:
     """(index_map callable, block_shape expr) from a BlockSpec call.
 
-    Either argument order; the index map may be an inline lambda or a
-    Name referring to a nested `def` in the enclosing function.
+    Either argument order; the index map may be an inline lambda, a
+    Name referring to a nested `def` in the enclosing function, or
+    `functools.partial` of such a def binding its keyword-only
+    parameters (one map per page slot, say).
     """
     fn: Optional[ast.AST] = None
     shape: Optional[ast.AST] = None
     candidates = list(spec.args) + [kw.value for kw in spec.keywords]
     for a in candidates:
+        if (isinstance(a, ast.Call) and a.args and fn is None
+                and (dotted_name(a.func) or "").split(".")[-1] == "partial"):
+            a = a.args[0]
         if isinstance(a, ast.Lambda) and fn is None:
             fn = a
         elif isinstance(a, ast.Name) and a.id in local_defs and fn is None:
@@ -246,6 +251,13 @@ class PallasConstraintsRule(Rule):
             bindings = env.get(gs.id)
             if bindings:
                 spec_sources.append(bindings[-1])
+        # ... or in a local list of specs spread into it (`*pages`)
+        for src in list(spec_sources):
+            for n in ast.walk(src):
+                if isinstance(n, ast.Starred) and isinstance(n.value, ast.Name) \
+                        and env.get(n.value.id) \
+                        and env[n.value.id][-1] not in spec_sources:
+                    spec_sources.append(env[n.value.id][-1])
         for spec in [s for src in spec_sources for s in _block_specs(src)]:
             imap, shape = _spec_parts(spec, local_defs)
             if imap is None:

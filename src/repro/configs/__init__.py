@@ -17,6 +17,7 @@ from .deepseek_7b import CONFIG as deepseek_7b
 from .deepseek_67b import CONFIG as deepseek_67b
 from .granite_moe_1b_a400m import CONFIG as granite_moe_1b_a400m
 from .jamba_v0_1_52b import CONFIG as jamba_v0_1_52b
+from .mellum2_12b_a2_5b import CONFIG as mellum2_12b_a2_5b
 from .mixtral_8x7b import CONFIG as mixtral_8x7b
 from .qwen1_5_0_5b import CONFIG as qwen1_5_0_5b
 from .qwen2_vl_2b import CONFIG as qwen2_vl_2b
@@ -34,6 +35,7 @@ ARCHS: Dict[str, ModelConfig] = {
     "jamba-v0.1-52b": jamba_v0_1_52b,
     "whisper-tiny": whisper_tiny,
     "xlstm-350m": xlstm_350m,
+    "mellum2-12b-a2.5b": mellum2_12b_a2_5b,
 }
 
 

@@ -14,7 +14,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["ModelConfig", "ShapeCell", "SHAPES", "input_specs", "make_smoke"]
+__all__ = ["ModelConfig", "RopeSpec", "ShapeCell", "SHAPES", "input_specs",
+           "make_smoke"]
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary embedding of one kind of layer: default RoPE at ``theta``,
+    or YaRN when ``yarn_factor`` is set (HF transformers'
+    ``_compute_yarn_parameters``: frequencies blended between
+    interpolation and extrapolation over the correction range of
+    ``beta_fast``/``beta_slow`` rotations at ``yarn_original_max``
+    positions, cos and sin scaled by ``yarn_attention_factor``)."""
+    theta: float = 10000.0
+    yarn_factor: Optional[float] = None
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,9 +50,18 @@ class ModelConfig:
     moe_experts: int = 0
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    # experts this chip holds of the router's ``moe_experts``: experts
+    # [offset, offset + held).  None holds them all.  Serving routes over
+    # every expert and computes the held experts' share (dropless)
+    moe_experts_held: Optional[int] = None
+    moe_expert_offset: int = 0
 
     # attention
-    window: Optional[int] = None            # SWA
+    window: Optional[int] = None            # SWA: the sliding layers' window
+    # per-layer attention kind, cycled over n_layers: "sliding" (window)
+    # or "full".  None: every layer slides when ``window`` is set
+    attn_kinds: Optional[Tuple[str, ...]] = None
+    rope_full: Optional[RopeSpec] = None    # full layers' RoPE, if not default
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     use_rope: bool = True
@@ -85,6 +111,11 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def experts_held(self) -> int:
+        return (self.moe_experts if self.moe_experts_held is None
+                else self.moe_experts_held)
+
+    @property
     def dtype(self):
         return jnp.dtype(self.param_dtype)
 
@@ -117,7 +148,8 @@ class ModelConfig:
             if spec.mlp == "dense":
                 total += d * f * (3 if self.gated_mlp else 2)
             elif spec.mlp == "moe":
-                total += self.moe_experts * d * f * (3 if self.gated_mlp else 2) + d * self.moe_experts
+                total += (self.experts_held * d * f * (3 if self.gated_mlp else 2)
+                          + d * self.moe_experts)
         if self.enc_layers:
             total += self.enc_layers * (4 * d * self.n_heads * hd + 2 * d * f)
             total += self.n_layers * 4 * d * self.n_heads * hd  # cross attn
@@ -216,6 +248,8 @@ def make_smoke(cfg: ModelConfig, **overrides) -> ModelConfig:
         head_dim=32,
         moe_experts=min(cfg.moe_experts, 4) if cfg.moe_experts else 0,
         moe_top_k=min(cfg.moe_top_k, 2) if cfg.moe_top_k else 0,
+        moe_experts_held=None,
+        moe_expert_offset=0,
         window=min(cfg.window, 32) if cfg.window else None,
         enc_layers=min(cfg.enc_layers, 2) if cfg.enc_layers else 0,
         enc_frames=16 if cfg.enc_layers else cfg.enc_frames,

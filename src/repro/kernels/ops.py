@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core.packing import BSRPlanes, BSRWeight
 from .block_sparse_matmul import bsr_matmul_pallas, bsr_planes_matmul_pallas
 from .epilogue import Epilogue, apply_epilogue, make_epilogue
+from .moe_experts import moe_experts_pallas, moe_experts_ref
 from .paged_attention import (
     paged_attention_decode_pallas,
     paged_attention_decode_ref,
@@ -40,7 +41,7 @@ from . import ref as _ref
 __all__ = [
     "Epilogue", "apply_epilogue", "make_epilogue",
     "bsr_matmul", "bsr_planes_matmul", "structure_norms", "on_tpu",
-    "paged_attention_decode", "paged_attention_prefill",
+    "paged_attention_decode", "paged_attention_prefill", "moe_experts",
 ]
 
 
@@ -110,7 +111,8 @@ def bsr_planes_matmul(
     return y.reshape(e, *lead, n)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "pages_per_step"))
+@functools.partial(jax.jit,
+                   static_argnames=("mode", "pages_per_step", "window"))
 def paged_attention_decode(
     q: jnp.ndarray,            # (B, H, dh) — rotated query, new token
     k_new: jnp.ndarray,        # (B, K, dh) — rotated K, new token (in-register)
@@ -122,22 +124,25 @@ def paged_attention_decode(
     *,
     mode: str = "auto",
     pages_per_step: int = 8,   # ref-path segment width (perf only)
+    window: Optional[int] = None,  # sliding window: keys > cache_len - W
 ) -> jnp.ndarray:
     """Fused paged decode attention: walks ``page_table`` with an online
     softmax, O(cache_len) work/traffic, no logical-view gather.  The new
     token's K/V never round-trips through the pool — it seeds the
-    accumulator in-register.  Returns (B, H, dh) fp32."""
+    accumulator in-register.  With ``window`` only the pages the window
+    sees are walked.  Returns (B, H, dh) fp32."""
     if _use_ref(mode):
         return paged_attention_decode_ref(
             q, k_new, v_new, k_pool, v_pool, page_table, cache_len,
-            pages_per_step=pages_per_step)
+            pages_per_step=pages_per_step, window=window)
     return paged_attention_decode_pallas(
         q, k_new, v_new, k_pool, v_pool, page_table, cache_len,
-        interpret=(mode == "interpret"))
+        interpret=(mode == "interpret"), window=window)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bm", "mode", "pages_per_step", "q_offset"))
+    jax.jit,
+    static_argnames=("bm", "mode", "pages_per_step", "q_offset", "window"))
 def paged_attention_prefill(
     q: jnp.ndarray,            # (B, S, H, dh) — rotated, pos [q_offset, q_offset+S)
     k_pool: jnp.ndarray,       # (P, K, page_size, dh) — context K/V already
@@ -149,6 +154,7 @@ def paged_attention_prefill(
     mode: str = "auto",
     pages_per_step: int = 8,
     q_offset: int = 0,         # static logical position of q row 0
+    window: Optional[int] = None,  # sliding window: keys > qpos - W
 ) -> jnp.ndarray:
     """Causal paged prefill attention over the same page walk (bm-tiled
     query blocks in the Pallas kernel).  ``q_offset > 0`` is the
@@ -160,10 +166,38 @@ def paged_attention_prefill(
     if _use_ref(mode):
         return paged_attention_prefill_ref(
             q, k_pool, v_pool, page_table, lengths,
-            pages_per_step=pages_per_step, q_offset=q_offset)
+            pages_per_step=pages_per_step, q_offset=q_offset, window=window)
     return paged_attention_prefill_pallas(
         q, k_pool, v_pool, page_table, lengths, bm=bm,
-        interpret=(mode == "interpret"), q_offset=q_offset)
+        interpret=(mode == "interpret"), q_offset=q_offset, window=window)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_rows", "activation", "mode"))
+def moe_experts(
+    x: jnp.ndarray,            # (R, D) rows grouped by held expert
+    w_gate: jnp.ndarray,       # (E, D, F) held experts
+    w_up: jnp.ndarray,         # (E, D, F)
+    w_down: jnp.ndarray,       # (E, F, D)
+    tile_expert: jnp.ndarray,  # (R // block_rows,) int32 held expert per tile
+    live_tiles: jnp.ndarray,   # (1,) int32 tiles holding rows
+    *,
+    block_rows: int,
+    activation: str = "silu",
+    mode: str = "auto",
+) -> jnp.ndarray:
+    """Held experts' gated FFN over rows grouped by expert (dropless MoE,
+    kernels/moe_experts.py): row r gets ``down(act(x_r gate) * x_r up)``
+    of its tile's expert, fp32.  Rows of dead tiles are zero on the ref
+    path and unwritten on the kernel's; callers read live rows only."""
+    if _use_ref(mode):
+        return moe_experts_ref(x, w_gate, w_up, w_down, tile_expert,
+                               live_tiles, block_rows=block_rows,
+                               activation=activation)
+    return moe_experts_pallas(x, w_gate, w_up, w_down, tile_expert,
+                              live_tiles, block_rows=block_rows,
+                              activation=activation,
+                              interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bn", "mode"))

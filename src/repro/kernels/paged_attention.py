@@ -24,7 +24,20 @@ shapes (:func:`decode_pages_per_step`).  The grid is statically sized
 by the table width, so the *step count* scales with ``max_pages``, not
 with ``cache_len``: a slot with a short context still walks every block,
 and its dead blocks pay the fixed per-step cost without their DMA.
-Prefill's grid is (batch, kv_head, query tile, page).
+Prefill's grid is (batch, kv_head, query tile, page block): one step
+scores a query tile against ``pages_per_step`` pages, as many as fill
+one 128-lane row of keys (:func:`prefill_pages_per_step`).
+
+**Sliding windows** (``window=W``, static): a query at position ``i``
+sees keys ``j`` with ``i - W < j <= i`` (HF's sliding-window causal
+mask).  Decode's walk then starts at the page holding the first visible
+position, ``max(cache_len - W + 1, 0)``, so no grid step and no DMA goes
+to a page wholly behind the window; the grid's block count is bounded by
+the window, not by the table width, and the partial first page is
+masked.  Prefill starts each query tile's page walk at the first page
+its earliest query can see.  Window calls carry their own kernel names,
+``paged_attention_decode_window`` / ``paged_attention_prefill_window``;
+``window=None`` builds the full-attention kernels.
 
 Online-softmax recurrence per page (all fp32):
 
@@ -69,15 +82,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "decode_blocks",
     "decode_pages_per_step",
     "paged_attention_decode_ref",
     "paged_attention_decode_pallas",
     "paged_attention_prefill_ref",
     "paged_attention_prefill_pallas",
+    "prefill_pages_per_step",
 ]
 
 NEG_INF = -1e30  # finite mask sentinel (matches models/attention.py)
 DECODE_VMEM_BUDGET = 4 * 1024 * 1024  # double-buffered K+V page blocks
+# query rows (tokens x GQA group) of one prefill tile: its fp32
+# accumulator, running stats and score tiles must fit scoped VMEM
+PREFILL_TILE_ROWS = 2048
+PREFILL_KEYS = 128  # key positions one prefill grid step scores
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -117,12 +136,15 @@ def paged_attention_decode_ref(
     cache_len: jnp.ndarray,    # (B,) int32 — #prior tokens (new token excluded)
     *,
     pages_per_step: int = 8,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Non-gathering reference: page-segment ``fori_loop`` bounded by
     ``max(cache_len)``, online softmax across segments.  Returns
     (B, H, dh) fp32.  At the kernel's ``pages_per_step`` it is
     bit-comparable to the Pallas kernel (same op order per block); other
-    widths stay within float rounding of it."""
+    widths stay within float rounding of it.  With ``window`` each row's
+    walk starts at the page of its first visible position, as the
+    kernel's does."""
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
@@ -147,17 +169,22 @@ def paged_attention_decode_ref(
     seg = pages_per_step * ps                               # positions / step
     offs = jnp.arange(ps, dtype=jnp.int32)
     page_idx = jnp.arange(pages_per_step, dtype=jnp.int32)
+    lo = (jnp.zeros_like(clen) if window is None
+          else jnp.maximum(clen - window + 1, 0))           # first visible
+    lo_page = lo // ps
 
     def body(j, carry):
         m, l, acc = carry
-        idx = j * pages_per_step + page_idx                 # logical pages
+        idx = lo_page[:, None] + j * pages_per_step + page_idx[None]  # (B, n)
         # clip the *lookup* (labels stay logical): positions past the
         # table are masked below, never mislabeled
-        pid = jnp.take(page_table, jnp.minimum(idx, max_pages - 1), axis=1)
+        pid = jnp.take_along_axis(page_table, jnp.minimum(idx, max_pages - 1),
+                                  axis=1)
         kp = _segment(k_pool, pid)                          # (B,K,seg,dh)
         vp = _segment(v_pool, pid)
-        pos = (idx[:, None] * ps + offs[None, :]).reshape(seg)
-        valid = (pos[None, :] < clen[:, None]) & (pos[None, :] < max_pages * ps)
+        pos = (idx[:, :, None] * ps + offs).reshape(b, seg)
+        valid = ((pos >= lo[:, None]) & (pos < clen[:, None])
+                 & (pos < max_pages * ps))
         s = jnp.einsum("bkgd,bksd->bkgs", qg, kp,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -172,7 +199,7 @@ def paged_attention_decode_ref(
             "bkgs,bksd->bkgd", p, vp, preferred_element_type=jnp.float32)
         return m2, l, acc
 
-    n_steps = (jnp.max(clen) + seg - 1) // seg
+    n_steps = jnp.max((clen - lo_page * ps + seg - 1) // seg)
     m, l, acc = jax.lax.fori_loop(0, n_steps, body, (m0, l0, acc0))
     return (acc / l).reshape(b, h, dh)
 
@@ -191,14 +218,29 @@ def decode_pages_per_step(kv_heads: int, page_size: int, head_dim: int,
     return pps
 
 
+def decode_blocks(max_pages: int, pages_per_step: int, page_size: int,
+                  window: int | None = None) -> int:
+    """Grid steps per slot of the decode walk: every block of the table,
+    or with ``window`` only as many as can hold the pages it sees — the
+    visible cached positions ``[cache_len - W + 1, cache_len)`` span at
+    most ``cdiv(W - 1, ps) + 1`` pages from the first visible one."""
+    blocks = _cdiv(max_pages, pages_per_step)
+    if window is None:
+        return blocks
+    return min(blocks, _cdiv(_cdiv(window - 1, page_size) + 1, pages_per_step))
+
+
 def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, *refs,
-                   page_size: int, pages_per_step: int, scale: float):
+                   page_size: int, pages_per_step: int, scale: float,
+                   window: int | None = None):
     """Grid (B, page blocks); one step covers one slot, all its KV heads
     and ``pages_per_step`` logical pages, each page one ``(1, K, ps, dh)``
     pool block of its own.  Scratch m/l/acc (K, G, ·) persists across
     the block dimension: j == 0 seeds from the in-register current
     token, blocks past the live context are skipped, the last step
-    normalizes into the output block."""
+    normalizes into the output block.  With ``window`` block 0 starts at
+    the page of the first visible position and positions before it are
+    masked."""
     pps = pages_per_step
     k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
@@ -207,6 +249,14 @@ def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, *refs,
     clen = clen_ref[bb]
     seg = pps * page_size
     qg = q_ref[0].astype(jnp.float32)                       # (K, G, dh)
+
+    def first_visible():
+        return jnp.maximum(clen - window + 1, 0)
+
+    def start():                # logical position of the block's first slot
+        if window is None:
+            return j * seg
+        return (first_visible() // page_size) * page_size + j * seg
 
     @pl.when(j == 0)
     def _seed():
@@ -217,7 +267,7 @@ def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, *refs,
         acc_ref[...] = jnp.broadcast_to(
             vn_ref[0].astype(jnp.float32), acc_ref.shape)
 
-    @pl.when(j * seg < clen)
+    @pl.when(start() < clen)
     def _block():
         kp = jnp.concatenate([r[0].astype(jnp.float32) for r in k_refs],
                              axis=1)                        # (K, seg, dh)
@@ -226,11 +276,15 @@ def _decode_kernel(tbl_ref, clen_ref, q_ref, kn_ref, vn_ref, *refs,
         s = jax.lax.dot_general(
             qg, kp, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale     # (K, G, seg)
-        pos = j * seg + jax.lax.broadcasted_iota(jnp.int32, (1, 1, seg), 2)
+        pos = start() + jax.lax.broadcasted_iota(jnp.int32, (1, 1, seg), 2)
         valid = pos < clen
+        if window is not None:
+            valid = valid & (pos >= first_visible())
         s = jnp.where(valid, s, NEG_INF)
-        kv_live = (j * seg + jax.lax.broadcasted_iota(
-            jnp.int32, (1, seg, 1), 1)) < clen
+        kv_pos = start() + jax.lax.broadcasted_iota(jnp.int32, (1, seg, 1), 1)
+        kv_live = kv_pos < clen
+        if window is not None:
+            kv_live = kv_live & (kv_pos >= first_visible())
         vp = jnp.where(kv_live, vp, 0.0)
         m = m_ref[...]
         m2 = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -258,9 +312,11 @@ def paged_attention_decode_pallas(
     *,
     pages_per_step: int | None = None,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Decode over the page walk; ``pages_per_step`` defaults to
-    :func:`decode_pages_per_step` of the shapes (tests pin it)."""
+    :func:`decode_pages_per_step` of the shapes (tests pin it).  With
+    ``window`` the walk covers only the pages the window can see."""
     b, h, dh = q.shape
     kvh = k_new.shape[1]
     g = h // kvh
@@ -278,13 +334,18 @@ def paged_attention_decode_pallas(
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
 
+    blocks = decode_blocks(max_pages, pps, ps, window)
+
     def page_map(i):
         def index(bb, j, tbl, cl):
             # clamp pages past the live context to the last live page: a
             # repeated block index skips the DMA, so traffic is
             # O(cache_len) though the grid walks every block
             live = (cl[bb] + ps - 1) // ps
-            jj = jnp.minimum(j * pps + i, jnp.maximum(live - 1, 0))
+            page = j * pps + i
+            if window is not None:       # from the first visible page
+                page = jnp.maximum(cl[bb] - window + 1, 0) // ps + page
+            jj = jnp.minimum(page, jnp.maximum(live - 1, 0))
             return (tbl[bb, jj], 0, 0, 0)
         return index
 
@@ -292,7 +353,7 @@ def paged_attention_decode_pallas(
     pages = [pl.BlockSpec((1, kvh, ps, dh), page_map(i)) for i in range(pps)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, _cdiv(max_pages, pps)),
+        grid=(b, blocks),
         in_specs=[
             pl.BlockSpec((1, kvh, g, dh), row_map),
             pl.BlockSpec((1, kvh, 1, dh), row_map),
@@ -314,11 +375,12 @@ def paged_attention_decode_pallas(
         )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, page_size=ps, pages_per_step=pps,
-                          scale=scale),
+                          scale=scale, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
         interpret=interpret,
-        name="paged_attention_decode",
+        name=("paged_attention_decode" if window is None
+              else "paged_attention_decode_window"),
         **kwargs,
     )(page_table, clen, qg, kn, vn, *(k_pool,) * pps, *(v_pool,) * pps)
     return out.reshape(b, h, dh)
@@ -337,6 +399,7 @@ def paged_attention_prefill_ref(
     *,
     pages_per_step: int = 8,
     q_offset: int = 0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Causal paged prefill reference: same page-segment walk as decode,
     vectorized over all S query rows.  With ``q_offset`` (static) the
@@ -345,7 +408,8 @@ def paged_attention_prefill_ref(
     prefill of a request whose first ``q_offset`` tokens are already
     cached in shared prefix pages (DESIGN.md §12).  ``lengths`` is the
     per-row *total* context (prefix + tail); rows at/past their length
-    get zero output.  Returns (B, S, H, dh) fp32."""
+    get zero output.  ``window`` adds the sliding-window mask.  Returns
+    (B, S, H, dh) fp32."""
     b, s, h, dh = q.shape
     kvh = k_pool.shape[1]
     g = h // kvh
@@ -375,6 +439,9 @@ def paged_attention_prefill_ref(
         valid = ((kvpos[None, None, :] <= qpos[None, :, None])
                  & (kvpos[None, None, :] < ln[:, None, None])
                  & (qpos[None, :, None] < ln[:, None, None]))
+        if window is not None:
+            valid = valid & (kvpos[None, None, :]
+                             > qpos[None, :, None] - window)
         kv_live = kvpos[None, :] < ln[:, None]              # (B, seg)
         sb = jnp.einsum("bkgqd,bksd->bkgqs", qg, kp,
                         preferred_element_type=jnp.float32) * scale
@@ -394,18 +461,46 @@ def paged_attention_prefill_ref(
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, dh)
 
 
-def _prefill_kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
-                    m_ref, l_ref, acc_ref, *, page_size: int, block_q: int,
-                    group: int, scale: float, q_offset: int):
-    """Grid (B, K, q_tiles, pages), pages innermost.  Query rows arrive
+def prefill_pages_per_step(page_size: int, pages: int) -> int:
+    """Pages one prefill grid step scores: as many as fill
+    ``PREFILL_KEYS`` key positions (one 128-lane row of scores, the
+    MXU's width), at most the ``pages`` a query tile walks.  Derived from
+    the shapes, never configured."""
+    return max(1, min(PREFILL_KEYS // page_size, pages))
+
+
+def _first_prefill_page(i, block_q: int, page_size: int, q_offset: int,
+                        window: int | None):
+    """The first page query tile ``i`` walks: 0, or with a window the page
+    of the first key its earliest query sees."""
+    if window is None:
+        return 0
+    return jnp.maximum(q_offset + i * block_q - window + 1, 0) // page_size
+
+
+def _prefill_kernel(tbl_ref, len_ref, q_ref, *refs, page_size: int,
+                    pages_per_step: int, block_q: int, group: int,
+                    scale: float, q_offset: int, window: int | None = None):
+    """Grid (B, K, q_tiles, page blocks), blocks innermost.  One step
+    scores a query tile against ``pages_per_step`` logical pages, each
+    page one ``(1, 1, ps, dh)`` pool block of its own.  Query rows arrive
     laid out (bm·G, dh) so one dot covers the whole GQA group; the causal
-    mask is built from 2D iotas (qpos = q_offset + row // G, kvpos = page
-    offset) — ``q_offset`` shifts every query to its logical position for
-    tail-only prefill over shared prefix pages (DESIGN.md §12)."""
+    mask is built from 2D iotas (qpos = q_offset + row // G, kvpos =
+    block offset) — ``q_offset`` shifts every query to its logical
+    position for tail-only prefill over shared prefix pages (DESIGN.md
+    §12).  With ``window`` the tile's walk starts at its first visible
+    page and keys at or before qpos - window are masked."""
+    pps = pages_per_step
+    k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
     bb = pl.program_id(0)
     i = pl.program_id(2)
     j = pl.program_id(3)
     ln = len_ref[bb]
+    seg = pps * page_size
+    # the logical position of the block's first key
+    start = (_first_prefill_page(i, block_q, page_size, q_offset, window)
+             * page_size + j * seg)
 
     @pl.when(j == 0)
     def _seed():
@@ -413,25 +508,29 @@ def _prefill_kernel(tbl_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # pages needed by this q tile: kvpos <= qpos < min(len, q_offset+(i+1)·bm)
+    # keys needed by this q tile: kvpos <= qpos < min(len, q_offset+(i+1)·bm)
     qhi = jnp.minimum(ln, q_offset + (i + 1) * block_q)
 
-    @pl.when(j * page_size < qhi)
-    def _page():
+    @pl.when(start < qhi)
+    def _block():
         qg = q_ref[0, 0].astype(jnp.float32)                # (bm·G, dh)
-        kp = kp_ref[0, 0].astype(jnp.float32)               # (ps, dh)
-        vp = vp_ref[0, 0].astype(jnp.float32)
+        kp = jnp.concatenate([r[0, 0].astype(jnp.float32) for r in k_refs],
+                             axis=0)                        # (seg, dh)
+        vp = jnp.concatenate([r[0, 0].astype(jnp.float32) for r in v_refs],
+                             axis=0)
         sb = jax.lax.dot_general(
             qg, kp, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (bm·G, ps)
-        shp = (block_q * group, page_size)
+            preferred_element_type=jnp.float32) * scale     # (bm·G, seg)
+        shp = (block_q * group, seg)
         row = jax.lax.broadcasted_iota(jnp.int32, shp, 0)
         qpos = q_offset + i * block_q + (row // group if group > 1 else row)
-        kvpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
+        kvpos = start + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
         valid = (kvpos <= qpos) & (kvpos < ln) & (qpos < ln)
+        if window is not None:
+            valid = valid & (kvpos > qpos - window)
         sb = jnp.where(valid, sb, NEG_INF)
-        kv_live = (j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < ln
+        kv_live = (start + jax.lax.broadcasted_iota(
+            jnp.int32, (seg, 1), 0)) < ln
         vp = jnp.where(kv_live, vp, 0.0)
         m = m_ref[...]
         m2 = jnp.maximum(m, jnp.max(sb, axis=-1, keepdims=True))
@@ -456,9 +555,13 @@ def paged_attention_prefill_pallas(
     lengths: jnp.ndarray,      # (B,) int32
     *,
     bm: int = 64,
+    pages_per_step: int | None = None,
     interpret: bool = False,
     q_offset: int = 0,
+    window: int | None = None,
 ) -> jnp.ndarray:
+    """Prefill over the page walk; ``pages_per_step`` defaults to
+    :func:`prefill_pages_per_step` of the shapes (tests pin it)."""
     b, s, h, dh = q.shape
     kvh = k_pool.shape[1]
     g = h // kvh
@@ -466,10 +569,14 @@ def paged_attention_prefill_pallas(
     scale = 1.0 / math.sqrt(dh)
     if not interpret:
         _check_page_tiling(k_pool)
-    bm = min(bm, s)
+    bm = min(bm, s, max(8, PREFILL_TILE_ROWS // g))
     s_pad = _cdiv(s, bm) * bm
     n_qt = s_pad // bm
     n_pg = _cdiv(q_offset + s, ps)                          # context pages only
+    if window is not None:
+        # a tile's keys span [first query - W + 1, last query]
+        n_pg = min(n_pg, _cdiv(bm + window - 1, ps) + 1)
+    pps = pages_per_step or prefill_pages_per_step(ps, n_pg)
     ln = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
 
     qt = q.reshape(b, s, kvh, g, dh).transpose(0, 2, 1, 3, 4)  # (B,K,S,G,dh)
@@ -477,19 +584,24 @@ def paged_attention_prefill_pallas(
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, s_pad - s), (0, 0), (0, 0)))
     qt = qt.reshape(b, kvh, s_pad * g, dh)           # GQA group rows adjacent
 
-    def pool_map(bb, k, i, j, tbl, cl):
+    def pool_map(bb, k, i, j, tbl, cl, *, p):
+        # page slot p of the block; pages past the tile's live keys clamp
+        # to its last live page: a repeated block index skips the DMA
         live = (jnp.minimum(cl[bb], q_offset + (i + 1) * bm) + ps - 1) // ps
-        jj = jnp.minimum(j, jnp.maximum(live - 1, 0))
+        page = _first_prefill_page(i, bm, ps, q_offset, window) + j * pps + p
+        jj = jnp.minimum(page, jnp.maximum(live - 1, 0))
         return (tbl[bb, jj], k, 0, 0)
 
     q_map = lambda bb, k, i, j, tbl, cl: (bb, k, i, 0)  # noqa: E731
+    pages = [pl.BlockSpec((1, 1, ps, dh), functools.partial(pool_map, p=p))
+             for p in range(pps)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, n_qt, n_pg),
+        grid=(b, kvh, n_qt, _cdiv(n_pg, pps)),
         in_specs=[
             pl.BlockSpec((1, 1, bm * g, dh), q_map),
-            pl.BlockSpec((1, 1, ps, dh), pool_map),
-            pl.BlockSpec((1, 1, ps, dh), pool_map),
+            *pages,                               # K, one per page slot
+            *pages,                               # V
         ],
         out_specs=pl.BlockSpec((1, 1, bm * g, dh), q_map),
         scratch_shapes=[
@@ -505,13 +617,15 @@ def paged_attention_prefill_pallas(
                                  "arbitrary"),
         )
     out = pl.pallas_call(
-        functools.partial(_prefill_kernel, page_size=ps, block_q=bm,
-                          group=g, scale=scale, q_offset=q_offset),
+        functools.partial(_prefill_kernel, page_size=ps, pages_per_step=pps,
+                          block_q=bm, group=g, scale=scale,
+                          q_offset=q_offset, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, s_pad * g, dh), jnp.float32),
         interpret=interpret,
-        name="paged_attention_prefill",
+        name=("paged_attention_prefill" if window is None
+              else "paged_attention_prefill_window"),
         **kwargs,
-    )(page_table, ln, qt, k_pool, v_pool)
+    )(page_table, ln, qt, *(k_pool,) * pps, *(v_pool,) * pps)
     out = out.reshape(b, kvh, s_pad, g, dh)[:, :, :s]
     return out.transpose(0, 2, 1, 3, 4).reshape(b, s, h, dh)

@@ -138,6 +138,7 @@ def attention_apply(
     window: Optional[int] = None,
     chunk: int = 512,
     rope_theta: float = 10000.0,
+    rope=None,                            # configs.base.RopeSpec
     mrope_sections: Optional[Tuple[int, ...]] = None,
     kv_input: Optional[jnp.ndarray] = None,   # cross-attention source
     use_rope: bool = True,
@@ -160,8 +161,8 @@ def attention_apply(
             q = apply_mrope(q, positions, mrope_sections, theta=rope_theta)
             k = apply_mrope(k, positions, mrope_sections, theta=rope_theta)
         else:
-            q = apply_rope(q, positions, theta=rope_theta)
-            k = apply_rope(k, positions, theta=rope_theta)
+            q = apply_rope(q, positions, theta=rope_theta, rope=rope)
+            k = apply_rope(k, positions, theta=rope_theta, rope=rope)
     o = chunked_causal_attention(q, k, v, causal=causal, window=window, chunk=chunk)
     o = logical_constraint(o, "batch", "seq", "heads", None)
     out = _wo_project(p, o, num_heads, head_dim, accum, x.dtype)
@@ -195,6 +196,14 @@ def _pages_view(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
         b, mp * ps, kvh, dh)
 
 
+def _check_paged_window(fn: str, window, page_table) -> None:
+    """A sliding window over a paged pool must see at least the query
+    itself; the page walk is sized from it."""
+    if page_table is not None and window is not None and window < 1:
+        raise ValueError(f"{fn}: window={window} with page_table — a "
+                         "sliding window must be >= 1 (None: full attention)")
+
+
 def attention_prefill(
     p: Dict,
     x: jnp.ndarray,                       # (B, S, D)
@@ -207,6 +216,7 @@ def attention_prefill(
     window: Optional[int] = None,
     chunk: int = 512,
     rope_theta: float = 10000.0,
+    rope=None,                            # configs.base.RopeSpec
     mrope_sections: Optional[Tuple[int, ...]] = None,
     use_rope: bool = True,
     accum=None,
@@ -230,8 +240,10 @@ def attention_prefill(
     runs *over the pages themselves* with the fused bm-tiled page-walk
     kernel (kernels/paged_attention.py, DESIGN.md §11) — no contiguous
     logical view is ever materialized.  ``paged_impl="gather"`` keeps
-    the legacy path for differential tests.  Ring (SWA) caches are not
-    paged.
+    the legacy path for differential tests.  A sliding ``window`` over a
+    pool masks keys at or before ``qpos - window`` and the fused kernel
+    walks only the pages each query tile can see; every page stays
+    allocated (there is no ring).
 
     ``start_pos`` (static, paged-only) runs a *tail-only* prefill: the
     tokens in ``x`` sit at logical positions ``[start_pos, start_pos+S)``
@@ -240,12 +252,7 @@ def attention_prefill(
     (DESIGN.md §12).  K/V scatter at the offset slots and attention
     covers the full ``start_pos + S`` context."""
     accum = accum or jnp.float32
-    if page_table is not None and window is not None:
-        raise NotImplementedError(
-            "attention_prefill: sliding-window attention over a paged KV "
-            f"cache is not implemented (window={window} with page_table) — "
-            "SWA uses contiguous ring caches (DESIGN.md §9); drop the "
-            "window or use a contiguous cache")
+    _check_paged_window("attention_prefill", window, page_table)
     if paged_impl not in ("fused", "gather"):
         raise ValueError(f"unknown paged_impl {paged_impl!r}")
     if start_pos and page_table is None:
@@ -267,8 +274,8 @@ def attention_prefill(
             q = apply_mrope(q, positions, mrope_sections, theta=rope_theta)
             k = apply_mrope(k, positions, mrope_sections, theta=rope_theta)
         else:
-            q = apply_rope(q, positions, theta=rope_theta)
-            k = apply_rope(k, positions, theta=rope_theta)
+            q = apply_rope(q, positions, theta=rope_theta, rope=rope)
+            k = apply_rope(k, positions, theta=rope_theta, rope=rope)
 
     alloc = cache["k"].shape[1]
     kc, vc = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
@@ -287,7 +294,8 @@ def attention_prefill(
             # pages (and unallocated ones) are never touched
             o = _paged_prefill_op(
                 q, ck, cv, page_table, jnp.full((b,), total, jnp.int32),
-                bm=min(chunk, s), q_offset=start_pos).astype(x.dtype)
+                bm=min(chunk, s), q_offset=start_pos,
+                window=window).astype(x.dtype)
         elif start_pos:
             # gather path with a prefix: materialize the logical view up
             # to the full context (every position < total is live), then
@@ -295,9 +303,9 @@ def attention_prefill(
             kv, vv = _pages_view(ck, page_table), _pages_view(cv, page_table)
             o = chunked_causal_attention(
                 q, kv[:, :total].astype(q.dtype), vv[:, :total].astype(q.dtype),
-                causal=True, window=None, chunk=chunk, q_offset=start_pos)
+                causal=True, window=window, chunk=chunk, q_offset=start_pos)
         else:
-            o = chunked_causal_attention(q, k, v, causal=True, window=None,
+            o = chunked_causal_attention(q, k, v, causal=True, window=window,
                                          chunk=chunk)
     elif s <= alloc:
         ck = jax.lax.dynamic_update_slice(cache["k"], kc, (0, 0, 0, 0))
@@ -358,6 +366,7 @@ def attention_decode(
     head_dim: int,
     window: Optional[int] = None,
     rope_theta: float = 10000.0,
+    rope=None,                            # configs.base.RopeSpec
     mrope_sections: Optional[Tuple[int, ...]] = None,
     use_rope: bool = True,
     update_cache: bool = True,
@@ -382,7 +391,9 @@ def attention_decode(
     DESIGN.md §11) — O(cache_len) traffic, the new token's K/V stays
     in-register; ``"gather"`` keeps the legacy logical-view gather
     (O(max_pages · page_size) traffic) for differential tests and
-    benchmarks.  Ring (SWA) caches are not paged.
+    benchmarks.  A sliding ``window`` over a pool sees keys after
+    ``cache_len - window``; the fused walk starts at the page of the
+    first of them (every page stays allocated: there is no ring).
 
     With the cache's seq dim sharded ("kv_seq"), GSPMD lowers the softmax
     to partial stats + all-reduce — the flash-decode pattern.
@@ -392,12 +403,7 @@ def attention_decode(
     cache_len = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
     paged = page_table is not None
-    if paged and window is not None:
-        raise NotImplementedError(
-            "attention_decode: sliding-window attention over a paged KV "
-            f"cache is not implemented (window={window} with page_table) — "
-            "SWA uses contiguous ring caches (DESIGN.md §9); drop the "
-            "window or use a contiguous cache")
+    _check_paged_window("attention_decode", window, page_table)
     if paged and not update_cache:
         raise ValueError("paged KV caches do not support cross-attention "
                          "reads")
@@ -417,8 +423,8 @@ def attention_decode(
             q = apply_mrope(q, pos3, mrope_sections, theta=rope_theta)
             knew = apply_mrope(knew, pos3, mrope_sections, theta=rope_theta)
         elif use_rope:
-            q = apply_rope(q, pos, theta=rope_theta)
-            knew = apply_rope(knew, pos, theta=rope_theta)
+            q = apply_rope(q, pos, theta=rope_theta, rope=rope)
+            knew = apply_rope(knew, pos, theta=rope_theta, rope=rope)
         if paged:
             # physical slot of this row's next token: its own page table
             # entry at logical page cache_len // page_size
@@ -444,7 +450,7 @@ def attention_decode(
         # accumulator in-register instead of round-tripping via the pool
         o32 = _paged_decode_op(
             q[:, 0], knew[:, 0], vnew[:, 0], cache["k"], cache["v"],
-            page_table, cache_len)
+            page_table, cache_len, window=window)
         o = dense(p["wo"], o32.astype(x.dtype).reshape(
             b, 1, num_heads * head_dim))
         return o, cache

@@ -202,11 +202,50 @@ def _rope_rotate(x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray) -> jnp.ndar
     ).astype(x.dtype)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10000.0) -> jnp.ndarray:
-    """x (B, S, H, dh), positions (B, S) -> rotated x."""
-    inv = rope_frequencies(x.shape[-1], theta)
+def yarn_frequencies(head_dim: int, rope) -> np.ndarray:
+    """YaRN inverse frequencies (dh/2,) of a ``RopeSpec`` with
+    ``yarn_factor`` set — HF transformers' ``_compute_yarn_parameters``
+    (truncated correction range): dims rotating more than ``beta_fast``
+    times over ``yarn_original_max`` positions keep their frequency,
+    those under ``beta_slow`` are interpolated by ``1/factor``, with a
+    linear ramp between."""
+    dim, base = head_dim, rope.theta
+
+    def correction_dim(rotations):
+        return (dim * math.log(rope.yarn_original_max
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(rope.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extrapolation = 1.0 / pos_freqs
+    interpolation = 1.0 / (rope.yarn_factor * pos_freqs)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp                      # share of the extrapolated freq
+    return (interpolation * (1.0 - keep) + extrapolation * keep).astype(
+        np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *,
+               theta: float = 10000.0, rope=None) -> jnp.ndarray:
+    """x (B, S, H, dh), positions (B, S) -> rotated x.  ``rope`` (a
+    ``configs.base.RopeSpec``) overrides ``theta``; with YaRN its
+    frequencies are :func:`yarn_frequencies` and cos/sin are scaled by
+    its attention factor."""
+    scale = 1.0
+    if rope is not None and rope.yarn_factor is not None:
+        inv = jnp.asarray(yarn_frequencies(x.shape[-1], rope))
+        scale = rope.yarn_attention_factor
+    else:
+        inv = rope_frequencies(x.shape[-1],
+                               rope.theta if rope is not None else theta)
     ang = positions.astype(jnp.float32)[..., None] * inv  # (B, S, dh/2)
     sin, cos = jnp.sin(ang)[:, :, None, :], jnp.cos(ang)[:, :, None, :]
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     return _rope_rotate(x, sin, cos)
 
 

@@ -1,5 +1,7 @@
 """Mixture-of-Experts: top-k token-choice routing with capacity, sort-based
-dispatch (no (T, E, C) one-hot blow-up), expert-parallel shardable.
+dispatch (no (T, E, C) one-hot blow-up), expert-parallel shardable — the
+training path — and the dropless serving layer :func:`moe_serve`, which
+computes the share of the experts this chip holds.
 
 Design (see DESIGN.md §4):
 * tokens are split into ``groups`` (sharded on the data axis) and routed
@@ -24,8 +26,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import BSRPlanes
 from repro.distributed.sharding import _ambient_mesh, logical_constraint
+from repro.kernels.moe_experts import ACTIVATIONS
 from repro.kernels.ops import Epilogue
+from repro.kernels.ops import moe_experts as _moe_experts_op
 from .layers import expert_matmul, matmul, truncated_normal_init
 
 
@@ -39,7 +44,7 @@ def _cap_axis_ok(num_experts: int) -> bool:
         return False
     return num_experts % mesh.shape["model"] == 0
 
-__all__ = ["moe_init", "moe_apply"]
+__all__ = ["moe_init", "moe_apply", "moe_serve"]
 
 
 def moe_init(
@@ -50,17 +55,21 @@ def moe_init(
     *,
     gated: bool = True,
     dtype=jnp.float32,
+    held: Optional[int] = None,
 ) -> Dict:
+    """Router over ``num_experts``; expert weights for the ``held`` of
+    them this chip holds (all by default)."""
+    held = num_experts if held is None else held
     ks = jax.random.split(key, 4)
     std_in = 1.0 / math.sqrt(d_model)
     std_out = 1.0 / math.sqrt(d_ff)
     p = {
         "router": {"kernel": truncated_normal_init(ks[0], (d_model, num_experts), std_in, jnp.float32)},
-        "experts_up": truncated_normal_init(ks[1], (num_experts, d_model, d_ff), std_in, dtype),
-        "experts_down": truncated_normal_init(ks[2], (num_experts, d_ff, d_model), std_out, dtype),
+        "experts_up": truncated_normal_init(ks[1], (held, d_model, d_ff), std_in, dtype),
+        "experts_down": truncated_normal_init(ks[2], (held, d_ff, d_model), std_out, dtype),
     }
     if gated:
-        p["experts_gate"] = truncated_normal_init(ks[3], (num_experts, d_model, d_ff), std_in, dtype)
+        p["experts_gate"] = truncated_normal_init(ks[3], (held, d_model, d_ff), std_in, dtype)
     return p
 
 
@@ -174,15 +183,107 @@ def moe_apply(
     return logical_constraint(out, "batch", "seq", "embed"), aux
 
 
-def moe_decode(p: Dict, x: jnp.ndarray, *, num_experts: int, top_k: int,
-               capacity_factor: float = 2.0,
-               activation: str = "silu") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Decode path: same sort-based dispatch, one group (T = B tokens).
+def _row_tile(pairs: int, num_experts: int) -> int:
+    """Rows per expert tile of the grouped kernel: the power of two nearest
+    above the rows a held expert expects under uniform routing, within
+    [16, 128] (16 fills a bf16 sublane tile; 128 an MXU pass)."""
+    want = max(1, -(-pairs // num_experts))
+    return int(min(128, max(16, 1 << (want - 1).bit_length())))
 
-    Per-token weight gathers would materialize (B·k·D·F) expert weights —
-    30 GB for mixtral at batch 128 — so decode reuses the capacity path
-    with a generous factor (token counts are tiny at decode)."""
-    return moe_apply(
-        p, x, num_experts=num_experts, top_k=top_k,
-        capacity_factor=capacity_factor, groups=1, activation=activation,
-    )
+
+def _held_ffn_grouped(xt, p, local, mine, held: int, tm: int,
+                      activation: str):
+    """(t, k, d) fp32 expert outputs of every routed pair that is held
+    here (zero elsewhere), through the grouped ``moe_experts`` kernel:
+    pairs laid out by held expert, each group padded to whole tiles."""
+    t, k = local.shape
+    d = xt.shape[-1]
+    flat = (local.reshape(t * k, 1) == jnp.arange(held)) & mine.reshape(
+        t * k, 1)                                            # (t·k, held)
+    counts = jnp.sum(flat, axis=0, dtype=jnp.int32)
+    tiles = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    live = tile_end[-1]
+    # static bound on the tiles: every token sends at most min(k, held)
+    # pairs here, and each group wastes less than a tile
+    n_tiles = max(1, (t * min(k, held) + held * (tm - 1)) // tm)
+    e_of = jnp.clip(local.reshape(t * k), 0, held - 1)
+    rank = jnp.take_along_axis(jnp.cumsum(flat, axis=0, dtype=jnp.int32),
+                               e_of[:, None], axis=1)[:, 0] - 1
+    pos = (tile_end - tiles)[e_of] * tm + rank
+    rows = n_tiles * tm
+    pos = jnp.where(mine.reshape(t * k), pos, rows)          # out of range
+    src = jnp.full((rows,), t, jnp.int32).at[pos].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    xs = jnp.where((src < t)[:, None], xt[jnp.minimum(src, t - 1)], 0)
+    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(live - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, tile, side="right"), held - 1
+    ).astype(jnp.int32)
+    ys = _moe_experts_op(xs, p["experts_gate"], p["experts_up"],
+                         p["experts_down"], tile_expert,
+                         live.reshape(1).astype(jnp.int32), block_rows=tm,
+                         activation=activation)
+    got = ys[jnp.minimum(pos, rows - 1)].reshape(t, k, d)
+    return jnp.where(mine[..., None], got, 0.0), counts
+
+
+def _held_ffn_planes(xt, p, local, mine, held: int, activation: str):
+    """The same for packed (``BSRPlanes``) expert weights: each held
+    expert runs over a buffer of all ``t`` token rows (a capacity of every
+    token, so nothing drops), through ``expert_matmul``."""
+    t, k = local.shape
+    d = xt.shape[-1]
+    tok = jnp.broadcast_to(jnp.arange(t)[:, None], (t, k))
+    buf = jnp.zeros((1, held, t, d), xt.dtype).at[
+        0, jnp.where(mine, local, held), tok].set(
+            jnp.broadcast_to(xt[:, None], (t, k, d)), mode="drop")
+    up = expert_matmul(buf, p["experts_up"], accum=jnp.float32)
+    h = expert_matmul(buf, p["experts_gate"], accum=jnp.float32,
+                      epilogue=Epilogue(activation=activation, multiplier=up))
+    out = expert_matmul(h.astype(xt.dtype), p["experts_down"],
+                        accum=jnp.float32)[0]                # (held, t, d)
+    got = out[jnp.clip(local, 0, held - 1), tok]             # (t, k, d)
+    counts = jnp.sum((local[..., None] == jnp.arange(held)) & mine[..., None],
+                     axis=(0, 1), dtype=jnp.int32)
+    return jnp.where(mine[..., None], got, 0.0), counts
+
+
+def moe_serve(p: Dict, x: jnp.ndarray, *, num_experts: int, top_k: int,
+              held: Optional[int] = None, offset: int = 0,
+              activation: str = "silu") -> Tuple[jnp.ndarray, Dict]:
+    """Dropless MoE for serving (gated experts), computing this chip's
+    share of the experts.  The router's softmax (fp32) and top-``top_k``
+    run over all ``num_experts``; the ``top_k`` weights are renormalised
+    to sum to 1.
+    Each token's output is the sum, over its routed experts that are held
+    here (``[offset, offset + held)``), of weight × the expert's gated
+    FFN; experts not held add nothing.  No capacity, nothing dropped:
+    every row's result depends on that row alone, so a request's tokens
+    are the same solo and co-batched.
+
+    Returns (output (B, S, D), stats): ``pairs`` routed (token, held
+    expert) pairs and ``touched`` held experts with at least one, int32."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"unsupported expert activation {activation!r}")
+    held = num_experts if held is None else held
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d)
+    logits = matmul(xt, p["router"]["kernel"], accum=jnp.float32)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gate, expert = jax.lax.top_k(probs, top_k)                # (t, k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    local = expert - offset
+    mine = (local >= 0) & (local < held)
+    names = ("experts_gate", "experts_up", "experts_down")
+    if any(isinstance(p[n], BSRPlanes) for n in names):
+        got, counts = _held_ffn_planes(xt, p, local, mine, held, activation)
+    else:
+        got, counts = _held_ffn_grouped(
+            xt, p, local, mine, held,
+            _row_tile(t * top_k, num_experts), activation)
+    y = jnp.sum(gate[..., None] * got, axis=1)                # (t, d) fp32
+    stats = {"pairs": jnp.sum(counts), "touched": jnp.sum(counts > 0,
+                                                           dtype=jnp.int32)}
+    return y.astype(x.dtype).reshape(b, s, d), stats

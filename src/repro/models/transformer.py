@@ -29,7 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, RopeSpec
 from repro.distributed.sharding import logical_constraint
 from . import attention as attn_mod
 from . import mamba as mamba_mod
@@ -62,7 +62,7 @@ from .mamba import (
     mamba_init,
     mamba_prefill,
 )
-from .moe import moe_apply, moe_decode, moe_init
+from .moe import moe_apply, moe_init, moe_serve
 from .moe_alltoall import alltoall_available, moe_alltoall_apply
 from .xlstm import (
     init_mlstm_cache,
@@ -91,21 +91,38 @@ class LayerSpec:
     cross_attn: bool = False
     causal: bool = True
     use_rope: bool = True
+    window: Optional[int] = None       # sliding-window size; None: full
+    rope: Optional[RopeSpec] = None    # None: default RoPE at cfg.rope_theta
+
+
+ATTN_KINDS = ("sliding", "full")
 
 
 def layer_specs(cfg: ModelConfig) -> List[LayerSpec]:
+    """One spec per layer, patterns cycled.  An attention layer's kind
+    (``cfg.attn_kinds``) gives its window — ``cfg.window`` for
+    "sliding", None for "full" — and full layers take ``cfg.rope_full``
+    where the config sets one."""
     mix = cfg.mixer_pattern or ("attn",)
     mlp = cfg.mlp_pattern or ("dense",)
-    return [
-        LayerSpec(
+    kinds = cfg.attn_kinds or (("sliding",) if cfg.window else ("full",))
+    if set(kinds) - set(ATTN_KINDS):
+        raise ValueError(f"attention kinds {kinds} not in {ATTN_KINDS}")
+    if "sliding" in kinds and not cfg.window:
+        raise ValueError("sliding attention layers need cfg.window")
+    specs = []
+    for i in range(cfg.n_layers):
+        kind = kinds[i % len(kinds)]
+        specs.append(LayerSpec(
             mixer=mix[i % len(mix)],
             mlp=mlp[i % len(mlp)],
             cross_attn=False,
             causal=True,
             use_rope=cfg.use_rope,
-        )
-        for i in range(cfg.n_layers)
-    ]
+            window=cfg.window if kind == "sliding" else None,
+            rope=cfg.rope_full if kind == "full" else None,
+        ))
+    return specs
 
 
 # ---------------------------------------------------------------------------
@@ -175,7 +192,7 @@ def _init_mlp(key, spec: LayerSpec, cfg: ModelConfig) -> Dict:
     if spec.mlp == "moe":
         return {"moe": moe_init(
             key, cfg.d_model, cfg.d_ff, cfg.moe_experts, gated=cfg.gated_mlp,
-            dtype=cfg.dtype)}
+            dtype=cfg.dtype, held=cfg.experts_held)}
     if spec.mlp == "none":
         return {}
     raise ValueError(f"unknown mlp {spec.mlp}")
@@ -232,8 +249,8 @@ def _apply_mixer(
         h = attention_apply(
             p["attn"], x,
             num_heads=cfg.n_heads, kv_heads=cfg.kv_heads, head_dim=cfg.head_dim_(),
-            positions=positions, causal=spec.causal, window=cfg.window,
-            chunk=cfg.attn_chunk, rope_theta=cfg.rope_theta,
+            positions=positions, causal=spec.causal, window=spec.window,
+            chunk=cfg.attn_chunk, rope_theta=cfg.rope_theta, rope=spec.rope,
             mrope_sections=cfg.mrope_sections, use_rope=spec.use_rope,
             accum=_accum(cfg), out_seq=_out_seq(cfg),
         )
@@ -278,7 +295,11 @@ def _apply_layer(
             out_seq=_out_seq(cfg), residual=x))
     elif spec.mlp == "moe":
         xn = _norm_apply(cfg, p["post_norm"], x)
-        if cfg.moe_impl == "alltoall" and alltoall_available(cfg.moe_experts):
+        if cfg.moe_experts_held is not None:
+            # one chip's share of the experts: only the dropless layer
+            # computes a share
+            y, _ = _moe_serve(cfg, p["moe"], xn)
+        elif cfg.moe_impl == "alltoall" and alltoall_available(cfg.moe_experts):
             y, aux = moe_alltoall_apply(
                 p["moe"], xn,
                 num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
@@ -292,6 +313,12 @@ def _apply_layer(
             )
         x = _residual(cfg, x + y)
     return x, aux
+
+
+def _moe_serve(cfg: ModelConfig, p: Dict, x: jnp.ndarray):
+    return moe_serve(p, x, num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+                     held=cfg.moe_experts_held, offset=cfg.moe_expert_offset,
+                     activation=cfg.activation)
 
 
 def _remat_wrap(fn, cfg: ModelConfig):
@@ -397,7 +424,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
             spec = LayerSpec(mixer="attn", mlp="dense", cross_attn=True,
                              use_rope=cfg.use_rope)
         if spec.mixer == "attn":
-            alloc = max_len if cfg.window is None else min(max_len, cfg.window)
+            alloc = max_len if spec.window is None else min(max_len, spec.window)
             c = init_kv_cache(batch, alloc, cfg.kv_heads, cfg.head_dim_(), dtype)
             if cfg.enc_layers > 0:
                 c["cross_k"] = jnp.zeros(
@@ -437,8 +464,13 @@ def lm_decode(
     batch: Dict[str, jnp.ndarray],
     cache_len: jnp.ndarray,
     cfg: ModelConfig,
-) -> Tuple[jnp.ndarray, List[Dict]]:
-    """One-token decode. batch["tokens"] (B, 1). Returns (logits, caches).
+    *,
+    moe_stats: bool = False,
+):
+    """One-token decode. batch["tokens"] (B, 1). Returns (logits, caches),
+    and with ``moe_stats`` a third value: the MoE layers' routed
+    (token, held expert) pairs and held experts touched, summed over
+    layers (int32 scalars).
 
     ``cache_len`` is a scalar or per-row ``(B,)`` vector (ragged prompts).
     With ``batch["page_tables"]`` (B, max_pages) the attention caches are
@@ -456,14 +488,17 @@ def lm_decode(
                            use_rope=cfg.use_rope)] * cfg.n_layers
 
     new_caches: List[Dict] = []
+    stats = {"pairs": jnp.zeros((), jnp.int32),
+             "touched": jnp.zeros((), jnp.int32)}
     for lp, spec, cache in zip(params["layers"], specs, caches):
         h_in = _norm_apply(cfg, lp["pre_norm"], x)
         if spec.mixer == "attn":
             h, cache2 = attention_decode(
                 lp["attn"], h_in, {"k": cache["k"], "v": cache["v"]}, cache_len,
                 num_heads=cfg.n_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim_(), window=cfg.window,
-                rope_theta=cfg.rope_theta, mrope_sections=cfg.mrope_sections,
+                head_dim=cfg.head_dim_(), window=spec.window,
+                rope_theta=cfg.rope_theta, rope=spec.rope,
+                mrope_sections=cfg.mrope_sections,
                 use_rope=spec.use_rope, page_table=page_tables,
                 paged_impl=cfg.paged_attn_impl,
             )
@@ -491,9 +526,9 @@ def lm_decode(
             x = mlp_apply(lp["mlp"], _norm_apply(cfg, lp["post_norm"], x),
                           activation=cfg.activation, residual=x)
         elif spec.mlp == "moe":
-            y, _ = moe_decode(lp["moe"], _norm_apply(cfg, lp["post_norm"], x),
-                              num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                              activation=cfg.activation)
+            y, st = _moe_serve(cfg, lp["moe"],
+                               _norm_apply(cfg, lp["post_norm"], x))
+            stats = {k: stats[k] + st[k] for k in stats}
             x = x + y
         new_caches.append(cache)
 
@@ -504,6 +539,8 @@ def lm_decode(
         # keep decode logits consistent with lm_forward/lm_prefill —
         # sampling inside lm_generate sees the same capped distribution
         logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
+    if moe_stats:
+        return logits, new_caches, stats
     return logits, new_caches
 
 
@@ -574,7 +611,8 @@ def lm_prefill(
                 "state the cached pages do not hold")
 
     # mirrors _apply_layer (which cannot thread caches) — keep residual
-    # sharding, out_seq and the MoE impl dispatch in sync with it
+    # sharding and out_seq in sync with it; MoE layers serve through the
+    # dropless moe_serve, as decode does (no capacity drops)
     x = logical_constraint(x, "batch", "seq", "embed")
     new_caches: List[Dict] = []
     for lp, spec, cache in zip(params["layers"], specs, caches):
@@ -584,8 +622,9 @@ def lm_prefill(
                 lp["attn"], h_in, cache,
                 num_heads=cfg.n_heads, kv_heads=cfg.kv_heads,
                 head_dim=cfg.head_dim_(), positions=positions,
-                window=cfg.window, chunk=cfg.attn_chunk,
-                rope_theta=cfg.rope_theta, mrope_sections=cfg.mrope_sections,
+                window=spec.window, chunk=cfg.attn_chunk,
+                rope_theta=cfg.rope_theta, rope=spec.rope,
+                mrope_sections=cfg.mrope_sections,
                 use_rope=spec.use_rope, accum=_accum(cfg),
                 out_seq=_out_seq(cfg), page_table=page_tables,
                 paged_impl=cfg.paged_attn_impl, start_pos=start_pos,
@@ -616,19 +655,8 @@ def lm_prefill(
                 activation=cfg.activation, accum=_accum(cfg),
                 out_seq=_out_seq(cfg), residual=x))
         elif spec.mlp == "moe":
-            xn = _norm_apply(cfg, lp["post_norm"], x)
-            if cfg.moe_impl == "alltoall" and alltoall_available(cfg.moe_experts):
-                y, _ = moe_alltoall_apply(
-                    lp["moe"], xn,
-                    num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                    capacity_factor=cfg.capacity_factor,
-                    activation=cfg.activation)
-            else:
-                y, _ = moe_apply(
-                    lp["moe"], xn,
-                    num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                    capacity_factor=cfg.capacity_factor,
-                    activation=cfg.activation)
+            y, _ = _moe_serve(cfg, lp["moe"],
+                              _norm_apply(cfg, lp["post_norm"], x))
             x = _residual(cfg, x + y)
         new_caches.append(cache)
 

@@ -74,9 +74,13 @@ packed-BSR params at every ``ticks_per_sync``.  Sampling params are
 per-request (``submit(..., temperature=, top_k=, top_p=)`` overriding
 the engine defaults) and ride the scan as ``(B,)`` vectors with a
 *per-slot* PRNG key seeded from the request id, so sampled streams are
-also independent of co-batching.  MoE archs run but route tokens jointly
-across the batch, so only dense/attention stacks carry the bit-identity
-guarantee.
+also independent of co-batching.  MoE layers serve through the dropless
+``models/moe.moe_serve`` — each token routed on its own, no capacity, no
+drops — so MoE stacks carry the same guarantee.
+
+Sliding-window layers (``LayerSpec.window``) share the page pools: their
+kernels walk only the pages inside the window, and pages behind it stay
+allocated until the request ends.
 """
 from __future__ import annotations
 
@@ -92,7 +96,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.analysis import runtime as analysis_runtime
 from repro.configs.base import ModelConfig
-from repro.kernels.paged_attention import decode_pages_per_step
+from repro.kernels.paged_attention import decode_blocks, decode_pages_per_step
 from repro.models import init_caches, layer_specs, lm_decode, lm_prefill
 from repro.models.transformer import _select_token_rows
 
@@ -189,15 +193,20 @@ def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
 
     Returns (token block (ticks, B), per-row emitted counts (B,),
     per-row bad flags (B,), last tok (B, 1), cache_len (B,),
-    rngs (B, 2), caches) in a single host transfer."""
+    rngs (B, 2), MoE counts (2,), caches) in a single host transfer.  The
+    MoE counts sum, over the ticks that ran and the MoE layers, the
+    routed (token, held expert) pairs and the held experts they touched
+    — of every row the layer computed, frozen and free rows included
+    (zeros for a stack without experts)."""
     b = tok.shape[0]
     done0 = budget_left <= 0          # free slots ride along frozen
     bad0 = jnp.zeros((b,), bool)
 
     def live_step(operand):
-        tok, clen, rngs, done, bad, left, cs = operand
-        logits, cs = lm_decode(
-            params, cs, {"tokens": tok, "page_tables": tables}, clen, cfg)
+        tok, clen, rngs, done, bad, left, moe_sum, cs = operand
+        logits, cs, moe = lm_decode(
+            params, cs, {"tokens": tok, "page_tables": tables}, clen, cfg,
+            moe_stats=True)
         last = logits[:, -1]
         if sampled:
             nxt, rngs2 = _select_token_rows(
@@ -223,7 +232,8 @@ def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
         clen = jnp.where(live, clen + 1, clen)
         rngs = jnp.where(live[:, None], rngs2, rngs)
         tok = jnp.where(live[:, None], nxt[:, None], tok)
-        return (tok, clen, rngs, done, bad, left, cs), (emit, live)
+        moe_sum = moe_sum + jnp.stack([moe["pairs"], moe["touched"]])
+        return (tok, clen, rngs, done, bad, left, moe_sum, cs), (emit, live)
 
     def step(carry, _):
         return jax.lax.cond(
@@ -231,11 +241,15 @@ def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
             lambda op: (op, (op[0][:, 0], jnp.zeros((b,), bool))),
             live_step, carry)
 
-    carry0 = (tok, cache_len, rngs, done0, bad0, budget_left, caches)
-    (tok, cache_len, rngs, _, bad, _, caches), (toks, lives) = jax.lax.scan(
-        step, carry0, None, length=ticks)
+    # the MoE counts ride the carry: as stacked scan outputs they changed
+    # XLA's buffer assignment of the pools (the qwen chunk's temporaries
+    # 4.4 -> 12.5 GB, half its throughput on the chip)
+    carry0 = (tok, cache_len, rngs, done0, bad0, budget_left,
+              jnp.zeros((2,), jnp.int32), caches)
+    (tok, cache_len, rngs, _, bad, _, moe, caches), (toks, lives) = \
+        jax.lax.scan(step, carry0, None, length=ticks)
     counts = jnp.sum(lives.astype(jnp.int32), axis=0)
-    return toks, counts, bad, tok, cache_len, rngs, caches
+    return toks, counts, bad, tok, cache_len, rngs, moe, caches
 
 
 class ServingEngine:
@@ -245,8 +259,8 @@ class ServingEngine:
     ----------
     params : dense or BSR-packed model pytree (both serve identically
         through the ``layers.matmul`` dispatch).
-    cfg : model config.  Paged caches do not support SWA ring windows or
-        encoder-decoder (whisper) stacks.
+    cfg : model config.  Paged caches do not support encoder-decoder
+        (whisper) stacks.
     num_slots : decode-batch rows; the jitted step shape never changes.
     page_size : tokens per physical KV page.  The compiled TPU kernels
         need a multiple of 8 (the fp32 pool's sublane tiling).
@@ -317,8 +331,6 @@ class ServingEngine:
         max_chunk_failures: int = 3,
         fault_injector=None,
     ):
-        if cfg.window is not None:
-            raise ValueError("paged KV caches do not support SWA windows")
         if cfg.enc_layers:
             raise ValueError("encoder-decoder archs are not paged-servable")
         if ticks_per_sync < 1:
@@ -402,17 +414,29 @@ class ServingEngine:
         self._next_rid = 0
         self.active_slot_ticks = 0
         self.decode_ticks = 0
-        # the TPU decode kernel's grid: per tick, every slot walks
-        # ceil(max_pages / pps) blocks of pps pages; only the pages its
-        # cache_len occupies are live.  Their ratio is the grid's share
-        # of real work (host counters from the cache_len mirror)
-        self.attn_live_page_ticks = 0
-        self.attn_walked_page_ticks = 0
+        # the TPU decode kernel's grid, per attention kind ("full",
+        # "sliding"): per tick, every slot walks decode_blocks(...) blocks
+        # of pps pages — all of the table, or those a window can hold;
+        # only the pages with positions its layers see are live (all of
+        # cache_len, or those inside the window).  Their ratio is the
+        # grid's share of real work (host counters from the cache_len
+        # mirror, per slot-tick of one layer of the kind)
         pps = decode_pages_per_step(kvh, page_size, hd, jnp.float32,
                                     self.max_pages)
-        self._attn_walk = (-(-self.max_pages // pps) * pps
-                           if any(spec.mixer == "attn"
-                                  for spec in self._specs) else 0)
+        self._attn_kinds: Dict[str, Optional[int]] = {
+            "sliding" if spec.window else "full": spec.window
+            for spec in self._specs if spec.mixer == "attn"}
+        self._attn_walk = {
+            kind: decode_blocks(self.max_pages, pps, page_size, w) * pps
+            for kind, w in self._attn_kinds.items()}
+        self.attn_live_page_ticks = {k: 0 for k in self._attn_kinds}
+        self.attn_walked_page_ticks = {k: 0 for k in self._attn_kinds}
+        # MoE layers' routed (token, held expert) pairs and held experts
+        # touched, summed over decode ticks and MoE layers (the chunk's
+        # own counts, carried on its one pull)
+        self.moe_layers = sum(spec.mlp == "moe" for spec in self._specs)
+        self.moe_routed_pairs = 0
+        self.moe_experts_touched = 0
         # declared host round-trips (analysis_stats / DESIGN.md §14):
         # one "decode_chunk" region per chunk, one "admission" region
         # per admitted request — everything else stays on device
@@ -857,14 +881,20 @@ class ServingEngine:
         """Add a committed chunk to the attention page counters.  A
         slot's context at tick ``t`` is its cache_len before the chunk
         plus the tokens it had emitted by then (``min(t, counts)``: a
-        frozen row stays where it stopped)."""
+        frozen row stays where it stopped).  A window layer's live pages
+        run from the page of its first visible position."""
         if not self._attn_walk:
             return
         ps = self.pool.page_size
         ctx = self._cache_len[None, :] + np.minimum(
             np.arange(ticks)[:, None], np.asarray(counts)[None, :])
-        self.attn_live_page_ticks += int(((ctx + ps - 1) // ps).sum())
-        self.attn_walked_page_ticks += ticks * self.num_slots * self._attn_walk
+        for kind, window in self._attn_kinds.items():
+            live = (ctx + ps - 1) // ps
+            if window is not None:
+                live = live - np.maximum(ctx - window + 1, 0) // ps
+            self.attn_live_page_ticks[kind] += int(live.sum())
+            self.attn_walked_page_ticks[kind] += (
+                ticks * self.num_slots * self._attn_walk[kind])
 
     def _chunk_call(self, left: np.ndarray, ticks: int):
         """(args, static kwargs) of the ``_decode_chunk`` call for the
@@ -954,8 +984,8 @@ class ServingEngine:
             with TraceAnnotation("repro.dispatch", ticks=ticks,
                                  active=len(active)):
                 args, static = self._chunk_call(left, ticks)
-                toks, counts, bad, tok, clen, rngs, caches = _decode_chunk(
-                    *args, **static)
+                toks, counts, bad, tok, clen, rngs, moe, caches = \
+                    _decode_chunk(*args, **static)
         except Exception as err:
             self._recover_chunk_failure(snap, err)
             self.tick += 1
@@ -966,10 +996,12 @@ class ServingEngine:
         # output in a single batched pull (device_get returns numpy)
         with analysis_runtime.sync_region("decode_chunk"):
             self.sync_regions["decode_chunk"] += 1
-            toks, counts, bad, tok, clen, rngs = jax.device_get(
-                (toks, counts, bad, tok, clen, rngs))
+            toks, counts, bad, tok, clen, rngs, moe = jax.device_get(
+                (toks, counts, bad, tok, clen, rngs, moe))
         with TraceAnnotation("repro.commit"):
             self._count_attn_pages(ticks, counts)
+            self.moe_routed_pairs += int(moe[0])
+            self.moe_experts_touched += int(moe[1])
             self._tok = np.array(tok)
             self._cache_len = np.array(clen)
             self._rngs = np.array(rngs)

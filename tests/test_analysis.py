@@ -371,6 +371,37 @@ def test_pallas_prefetch_grid_spec_arity(tmp_path):
     assert "takes 2 args but grid rank 2 + 1" in findings[0].message
 
 
+def test_pallas_partial_index_maps_in_a_spread_spec_list(tmp_path):
+    """One index map per page slot -- ``functools.partial`` of a nested
+    def binding a keyword-only slot, spread into the grid spec from a
+    local list -- is checked like a plain one: its arity counts the
+    positional parameters, the bound slot is no capture, a traced
+    capture is flagged once however often the list is spread."""
+    findings, _ = _scan(tmp_path, """
+        import functools
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        import jax.numpy as jnp
+
+        def run(x, tbl, *, interpret=False):
+            live = jnp.sum(tbl)              # traced!
+            def pool_map(i, j, t, *, p):
+                return (live + i + p, j)
+            pages = [pl.BlockSpec((8, 8), functools.partial(pool_map, p=p))
+                     for p in range(2)]
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(4, 2),
+                in_specs=[*pages, *pages],
+                out_specs=pl.BlockSpec((8, 8), lambda i, j, t: (i, j)),
+            )
+            return pl.pallas_call(
+                kern, grid_spec=grid_spec, interpret=interpret)(x, tbl)
+        """, enabled="pallas-constraints")
+    assert len(findings) == 1
+    assert "`pool_map` captures `live`" in findings[0].message
+
+
 # ---------------------------------------------------------------------------
 # framework: suppressions, toggles, baseline, keys
 # ---------------------------------------------------------------------------

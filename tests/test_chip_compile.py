@@ -5,7 +5,9 @@ and compile every serving kernel at published model widths against a
 ``v5e:2x2`` topology description: the TPU compiler runs on the host and
 refuses what the chip would refuse (unaligned blocks, VMEM overflow).
 Nothing executes.  Widths: qwen1.5-0.5b (H = K = 16, dh 64, d_ff 2816)
-and granite-moe-1b-a400m (H 16, K 8, 32 experts of d_ff 512).
+granite-moe-1b-a400m (H 16, K 8, 32 experts of d_ff 512) and
+mellum2-12b-a2.5b (H 32, K 4, dh 128, 1024-token windows, held experts of
+d 2304 x 896).
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker
@@ -26,6 +28,7 @@ from repro.kernels.block_sparse_matmul import (
     bsr_planes_matmul_pallas,
 )
 from repro.kernels.epilogue import Epilogue
+from repro.kernels.moe_experts import moe_experts_pallas
 from repro.kernels.paged_attention import (
     paged_attention_decode_pallas,
     paged_attention_prefill_pallas,
@@ -142,6 +145,55 @@ def test_paged_kernels_refuse_untileable_page_size(one_chip):
         jax.jit(paged_attention_decode_pallas).lower(*args)
 
 
+# the mellum2-mixed-backlog cell: 16 slots, 3072 context in 16-token
+# pages (192-page tables over a 3073-page fp32 pool), 32 query heads over
+# 4 KV heads of 128, 1024-token windows
+MELLUM = dict(b=16, h=32, kvh=4, dh=128, ps=16, mp=192, pages=3073)
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_mellum2_paged_decode_compiles_for_v5e(one_chip, window):
+    m = MELLUM
+    pool = _sds((m["pages"], m["kvh"], m["ps"], m["dh"]), "float32", one_chip)
+    args = (_sds((m["b"], m["h"], m["dh"]), "bfloat16", one_chip),
+            _sds((m["b"], m["kvh"], m["dh"]), "bfloat16", one_chip),
+            _sds((m["b"], m["kvh"], m["dh"]), "bfloat16", one_chip),
+            pool, pool, _sds((m["b"], m["mp"]), "int32", one_chip),
+            _sds((m["b"],), "int32", one_chip))
+    _compile(lambda *a: paged_attention_decode_pallas(*a, window=window),
+             *args)
+
+
+@pytest.mark.parametrize("s", [256, 2560])
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_mellum2_paged_prefill_compiles_for_v5e(one_chip, window, s):
+    """The cell's shortest and longest prompts at the program's default
+    512-token tile, which the kernel narrows to fit 8 query heads per KV
+    head in scoped VMEM."""
+    m = MELLUM
+    pool = _sds((m["pages"], m["kvh"], m["ps"], m["dh"]), "float32", one_chip)
+    _compile(lambda *a: paged_attention_prefill_pallas(*a, bm=512,
+                                                       window=window),
+             _sds((1, s, m["h"], m["dh"]), "bfloat16", one_chip), pool, pool,
+             _sds((1, m["mp"]), "int32", one_chip),
+             _sds((1,), "int32", one_chip))
+
+
+@pytest.mark.parametrize("tm,tiles", [(16, 15), (128, 167)],
+                         ids=["decode-16-rows", "prefill-2560-tokens"])
+def test_moe_experts_compiles_for_v5e(one_chip, tm, tiles):
+    """Held experts' grouped FFN at mellum2's widths (8 held experts of
+    d 2304 x 896, bf16): a decode tick of 16 tokens and a 2560-token
+    prefill, each at the static tile bound moe_serve gives them."""
+    d, f, e = 2304, 896, 8
+    _compile(lambda *a: moe_experts_pallas(*a, block_rows=tm),
+             _sds((tiles * tm, d), "bfloat16", one_chip),
+             _sds((e, d, f), "bfloat16", one_chip),
+             _sds((e, d, f), "bfloat16", one_chip),
+             _sds((e, f, d), "bfloat16", one_chip),
+             _sds((tiles,), "int32", one_chip), _sds((1,), "int32", one_chip))
+
+
 def _bsr_spec(k, n, sharding, planes=None):
     """Shape-only BSR weight at 50% tile density, 128x128 blocks."""
     gk, gn = k // BLOCK, n // BLOCK
@@ -203,24 +255,35 @@ def _named_kernels(hlo: str, kernel: str) -> int:
 def _kernel_call(kernel, sharding):
     """(body, carry, rest) of one call of ``kernel``'s ``pallas_call``,
     with no jit of its own around it; the output is shaped like the
-    carry."""
-    if kernel == "paged_attention_decode":
+    carry.  A ``*_window`` attention kernel is the same call with a
+    sliding window."""
+    window = 64 if kernel.endswith("_window") else None
+    if kernel.startswith("paged_attention_decode"):
         b, h, dh, ps, mp = 8, 16, 64, 16, 16
         pool = _sds((b * mp + 1, h, ps, dh), "float32", sharding)
         rest = (_sds((b, h, dh), "bfloat16", sharding),
                 _sds((b, h, dh), "bfloat16", sharding), pool, pool,
                 _sds((b, mp), "int32", sharding), _sds((b,), "int32", sharding))
         return (lambda q, *a: paged_attention_decode_pallas(
-            q, *a).astype(q.dtype),
+            q, *a, window=window).astype(q.dtype),
             _sds((b, h, dh), "bfloat16", sharding), rest)
-    if kernel == "paged_attention_prefill":
+    if kernel.startswith("paged_attention_prefill"):
         s, h, dh, ps, mp = 128, 16, 64, 16, 16
         pool = _sds((mp + 1, h, ps, dh), "float32", sharding)
         rest = (pool, pool, _sds((1, mp), "int32", sharding),
                 _sds((1,), "int32", sharding))
         return (lambda q, *a: paged_attention_prefill_pallas(
-            q, *a).astype(q.dtype),
+            q, *a, window=window).astype(q.dtype),
             _sds((1, s, h, dh), "bfloat16", sharding), rest)
+    if kernel == "moe_experts":
+        tm, tiles, d, f, e = 16, 4, 256, 128, 4
+        rest = (_sds((e, d, f), "bfloat16", sharding),
+                _sds((e, d, f), "bfloat16", sharding),
+                _sds((e, f, d), "bfloat16", sharding),
+                _sds((tiles,), "int32", sharding), _sds((1,), "int32", sharding))
+        return (lambda x, *a: moe_experts_pallas(
+            x, *a, block_rows=tm).astype(x.dtype),
+            _sds((tiles * tm, d), "bfloat16", sharding), rest)
     if kernel == "bsr_matmul":
         return (bsr_matmul_pallas, _sds((8, 1024), "bfloat16", sharding),
                 (_bsr_spec(1024, 1024, sharding),))
@@ -231,7 +294,10 @@ def _kernel_call(kernel, sharding):
 
 @pytest.mark.parametrize("kernel", ["paged_attention_decode",
                                     "paged_attention_prefill",
-                                    "bsr_matmul", "bsr_planes_matmul"])
+                                    "bsr_matmul", "bsr_planes_matmul",
+                                    "paged_attention_decode_window",
+                                    "paged_attention_prefill_window",
+                                    "moe_experts"])
 def test_kernels_keep_their_names_in_the_compiled_program(one_chip, kernel):
     """Each Pallas kernel compiles to a ``tpu_custom_call`` instruction
     named by its ``pallas_call(name=...)``, not after whichever jit
@@ -249,3 +315,37 @@ def test_kernels_keep_their_names_in_the_compiled_program(one_chip, kernel):
     assert _named_kernels(hlo, kernel) >= 1, \
         [ln.strip()[:100] for ln in hlo.splitlines()
          if "tpu_custom_call" in ln]
+
+
+def test_dense_decode_chunk_keeps_its_temporaries_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The dense decode cells' whole 8-tick ``_decode_chunk``
+    (qwen1.5-0.5b at published widths, 16 slots, 40-page tables over a
+    641-page fp32 pool), compiled with its Pallas kernels for the
+    described chip: its temporaries stay at the 4.44 GB they have held
+    since the cells were added.  Adding the MoE counts to the scan's
+    stacked outputs once tipped XLA's buffer assignment of the pools to
+    12.5 GB and halved the cells' tokens/s on the chip; the counts ride
+    the scan's carry instead."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import init_params
+    from repro.serving.engine import _decode_chunk
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the kernels, not refs
+    cfg = get_config("qwen1.5-0.5b")
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    b, ps, mp = 16, 16, 40
+    pool = _sds((b * mp + 1, cfg.kv_heads, ps, cfg.head_dim_()), "float32",
+                one_chip)
+    caches = [{"k": pool, "v": pool} for _ in range(cfg.n_layers)]
+    vec = lambda dt: _sds((b,), dt, one_chip)  # noqa: E731
+    compiled = _decode_chunk.lower(
+        params, caches, _sds((b, 1), "int32", one_chip), vec("int32"),
+        _sds((b, mp), "int32", one_chip), _sds((b, 2), "uint32", one_chip),
+        vec("float32"), vec("int32"), vec("float32"), vec("int32"),
+        cfg=cfg, ticks=8, eos_id=None, sampled=False, guard=True).compile()
+    assert _named_kernels(compiled.as_text(), "paged_attention_decode") >= 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4.5e9

@@ -20,11 +20,13 @@ import pytest
 from repro.kernels import paged_attention_decode, paged_attention_prefill
 from repro.kernels.paged_attention import (
     DECODE_VMEM_BUDGET,
+    decode_blocks,
     decode_pages_per_step,
     paged_attention_decode_pallas,
     paged_attention_decode_ref,
     paged_attention_prefill_pallas,
     paged_attention_prefill_ref,
+    prefill_pages_per_step,
 )
 from repro.models.attention import attention_decode, attention_init, full_attention
 
@@ -57,9 +59,10 @@ def _mk_decode(rng, b, h, kvh, dh, ps, max_pages, clens, poison=False):
             jnp.asarray(clens, jnp.int32))
 
 
-def _decode_oracle(q, kn, vn, kp, vp, tbl, clen):
+def _decode_oracle(q, kn, vn, kp, vp, tbl, clen, window=None):
     """Dense gather + monolithic softmax, the new token appended at its
-    row's cache_len — the legacy view the kernel must reproduce."""
+    row's cache_len — the legacy view the kernel must reproduce.  With
+    ``window`` a row at cache_len c sees positions after c - window."""
     b, h, dh = q.shape
     kvh = kn.shape[1]
     g = h // kvh
@@ -74,6 +77,8 @@ def _decode_oracle(q, kn, vn, kp, vp, tbl, clen):
     qg = np.asarray(q).reshape(b, kvh, g, dh)
     s = np.einsum("bkgd,bskd->bkgs", qg, ck) / np.sqrt(dh)
     valid = np.arange(s_max)[None] <= np.asarray(clen)[:, None]
+    if window is not None:
+        valid &= np.arange(s_max)[None] > np.asarray(clen)[:, None] - window
     s = np.where(valid[:, None, None], s, -1e30)
     w = np.exp(s - s.max(-1, keepdims=True))
     w /= w.sum(-1, keepdims=True)
@@ -345,3 +350,161 @@ def test_paged_prefill_q_offset_ragged_and_ops_dispatch():
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got[1, 25 - start:],
                                   np.zeros_like(got[1, 25 - start:]))
+
+
+# ---------------------------------------------------------------------------
+# Sliding windows
+# ---------------------------------------------------------------------------
+
+# (ps, window, pages_per_step): a window smaller than a page, windows that
+# are no multiple of pps * ps, and one spanning several blocks
+WINDOW_CASES = [(8, 5, 2), (4, 13, 2), (4, 6, 4), (8, 20, 1), (16, 40, 2)]
+
+
+@pytest.mark.parametrize("ps,window,pps", WINDOW_CASES,
+                         ids=[f"ps{p}-w{w}-pps{n}" for p, w, n in WINDOW_CASES])
+def test_paged_decode_window_kernel_matches_ref_and_oracle(ps, window, pps):
+    """Windowed decode: kernel (interpret) vs ref at the same block width
+    vs the dense oracle with the sliding mask, for contexts below, at and
+    past the window (up to 3x), an empty row included.  Every position
+    outside a row's window or context is NaN-poisoned: the partial first
+    page is masked and no page behind the window leaks into the result."""
+    rng = np.random.default_rng(ps * 100 + window)
+    b, h, kvh, dh = 7, 8, 2, 16
+    mp = -(-(3 * window + 1) // ps)
+    clens = [0, window - 2 if window > 2 else 1, window - 1, window,
+             window + 1, 2 * window + 3, 3 * window]
+    q, kn, vn, kp, vp, tbl, clen = _mk_decode(rng, b, h, kvh, dh, ps, mp,
+                                              clens)
+    kp, vp = np.array(kp), np.array(vp)
+    tb = np.asarray(tbl)
+    for r, c in enumerate(clens):
+        for t in range(max(c - window + 1, 0)):   # behind the window
+            kp[tb[r, t // ps], :, t % ps] = np.nan
+            vp[tb[r, t // ps], :, t % ps] = np.nan
+    args = (q, kn, vn, jnp.asarray(kp), jnp.asarray(vp), tbl, clen)
+    ref = paged_attention_decode_ref(*args, pages_per_step=pps, window=window)
+    ker = paged_attention_decode_pallas(*args, pages_per_step=pps,
+                                        interpret=True, window=window)
+    assert np.isfinite(np.asarray(ker)).all()
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=ATOL)
+    clean = [np.nan_to_num(a) for a in (kp, vp)]
+    orc = _decode_oracle(q, kn, vn, jnp.asarray(clean[0]),
+                         jnp.asarray(clean[1]), tbl, clen, window=window)
+    np.testing.assert_allclose(np.asarray(ref), orc, atol=1e-5)
+
+
+@pytest.mark.parametrize("mp,pps,ps,window,want", [
+    (192, 32, 16, 1024, 3),      # the mellum2 cell: 65 pages, 3 blocks
+    (192, 32, 16, None, 6),      # its full layers walk the whole table
+    (40, 8, 16, None, 5),        # the qwen decode cells, unchanged
+    (8, 2, 8, 5, 1),             # a window inside one page
+    (8, 2, 4, 13, 2),            # 4 visible pages + a partial one
+    (3, 4, 16, 1024, 1),         # capped by the table
+])
+def test_decode_blocks_bounded_by_the_window(mp, pps, ps, window, want):
+    assert decode_blocks(mp, pps, ps, window) == want
+
+
+@pytest.mark.parametrize("ps,window,bm,start", [
+    (8, 5, 16, 0), (4, 13, 16, 0), (8, 20, 32, 0), (4, 13, 16, 24),
+    (16, 24, 16, 16)])
+def test_paged_prefill_window_kernel_matches_ref_and_oracle(ps, window, bm,
+                                                            start):
+    """Windowed prefill: kernel (interpret) vs ref vs the unchunked
+    sliding-window oracle, prompt ~3x the window, whole and as a tail
+    over cached pages (``q_offset``); a query tile starts its walk at the
+    first page its earliest query sees."""
+    rng = np.random.default_rng(ps + window + bm + start)
+    b, s, h, kvh, dh = 2, 3 * window + 5, 8, 2, 16
+    q, k, v, kp, vp, tbl = _mk_prefill(rng, b, s, h, kvh, dh, ps)
+    lengths = jnp.full((b,), s, jnp.int32)
+    ref = paged_attention_prefill_ref(q[:, start:], kp, vp, tbl, lengths,
+                                      pages_per_step=2, q_offset=start,
+                                      window=window)
+    ker = paged_attention_prefill_pallas(q[:, start:], kp, vp, tbl, lengths,
+                                         bm=bm, interpret=True,
+                                         q_offset=start, window=window)
+    assert np.isfinite(np.asarray(ker)).all()
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=ATOL)
+    orc = full_attention(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(orc)[:, start:],
+                               atol=1e-5)
+
+
+def test_paged_window_wider_than_context_matches_full_bitwise():
+    """A window wider than every context gives bit-identical results
+    through the windowed kernels as the ``window=None`` full-attention
+    kernels."""
+    rng = np.random.default_rng(31)
+    dec = _mk_decode(rng, 3, 8, 2, 16, 8, 4, [0, 9, 31])
+    full = paged_attention_decode_pallas(*dec, pages_per_step=2,
+                                         interpret=True)
+    wide = paged_attention_decode_pallas(*dec, pages_per_step=2,
+                                         interpret=True, window=64)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(wide))
+    q, k, v, kp, vp, tbl = _mk_prefill(rng, 1, 40, 8, 2, 16, 8)
+    ln = jnp.full((1,), 40, jnp.int32)
+    pf = paged_attention_prefill_pallas(q, kp, vp, tbl, ln, bm=16,
+                                        interpret=True)
+    pw = paged_attention_prefill_pallas(q, kp, vp, tbl, ln, bm=16,
+                                        interpret=True, window=64)
+    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pw))
+
+
+# ---------------------------------------------------------------------------
+# Prefill page blocks
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("ps,pages,want", [
+    (16, 160, 8),        # the mellum2 cell's full layers: 128 keys a step
+    (16, 81, 8),         # its window layers (a 256-token tile + 1023)
+    (16, 8, 8),          # the qwen cells' 128-token prompts: one step
+    (16, 3, 3),          # a short prompt: no more pages than it has
+    (4, 100, 32),
+    (8, 69, 16),
+    (128, 10, 1),        # a page already fills the lane row
+    (256, 10, 1),
+])
+def test_prefill_pages_per_step_fills_one_lane_row(ps, pages, want):
+    assert prefill_pages_per_step(ps, pages) == want
+
+
+# (pps, window, q_offset): one page a step (the former grid), a width that
+# divides no window, one wider than the tile's walk, each whole and as a
+# tail over cached pages
+PREFILL_BLOCK_CASES = [(1, None, 0), (3, None, 0), (3, None, 24), (5, 13, 0),
+                       (2, 13, 24), (16, 13, 8), (16, None, 0)]
+
+
+@pytest.mark.parametrize(
+    "pps,window,start", PREFILL_BLOCK_CASES,
+    ids=[f"pps{p}-w{w}-q{o}" for p, w, o in PREFILL_BLOCK_CASES])
+def test_paged_prefill_page_blocks_match_ref_and_oracle(pps, window, start):
+    """The prefill kernel (interpret) at pinned page-block widths against
+    the ref and the unchunked oracle, on ragged lengths (an empty row, a
+    row ending mid-page) and a prompt off the tile grid.  Every pool slot
+    the prompts never wrote is NaN, so a block that reads past a row's
+    pages, or a page slot clamped to the last live page, leaks loudly."""
+    rng = np.random.default_rng(37 * pps + start)
+    b, s, h, kvh, dh, ps = 3, 45, 8, 2, 16, 4
+    q, k, v, kp, vp, tbl = _mk_prefill(rng, b, s, h, kvh, dh, ps)
+    lens = [start, start + 11, s]
+    lengths = jnp.asarray(lens, jnp.int32)
+    ref = paged_attention_prefill_ref(q[:, start:], kp, vp, tbl, lengths,
+                                      pages_per_step=2, q_offset=start,
+                                      window=window)
+    ker = paged_attention_prefill_pallas(q[:, start:], kp, vp, tbl, lengths,
+                                         bm=16, pages_per_step=pps,
+                                         interpret=True, q_offset=start,
+                                         window=window)
+    got = np.asarray(ker)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(ref), atol=ATOL)
+    orc = np.asarray(full_attention(q, k, v, causal=True, window=window))
+    for r, ln in enumerate(lens):
+        live = ln - start
+        np.testing.assert_allclose(got[r, :live], orc[r, start:ln], atol=1e-5)
+        np.testing.assert_array_equal(got[r, live:],
+                                      np.zeros_like(got[r, live:]))
+

@@ -247,20 +247,21 @@ def test_attention_prefill_paged_writes_match_contiguous():
 
 
 def test_attention_paged_rejects_windows_with_clear_error():
-    """SWA over a paged cache is unsupported: both entry points must say
-    so loudly (NotImplementedError naming the combo), not silently
-    mis-compute or raise a generic error."""
+    """Sliding windows over a paged cache are served (the windowed page
+    walk, tests/test_paged_attention.py); a window that could not see
+    even the query itself is refused by both entry points with an error
+    naming the combination, not mis-computed."""
     p = attention_init(jax.random.PRNGKey(0), 32, 2, 2, 16)
     cache = {"k": jnp.zeros((4, 2, 2, 16)), "v": jnp.zeros((4, 2, 2, 16))}
     table = jnp.zeros((1, 2), jnp.int32)
-    with pytest.raises(NotImplementedError, match="window=8.*page_table"):
+    with pytest.raises(ValueError, match="window=0.*page_table"):
         attention_decode(p, jnp.zeros((1, 1, 32)), cache,
                          jnp.zeros((1,), jnp.int32),
-                         num_heads=2, kv_heads=2, head_dim=16, window=8,
+                         num_heads=2, kv_heads=2, head_dim=16, window=0,
                          page_table=table)
-    with pytest.raises(NotImplementedError, match="window=8.*page_table"):
+    with pytest.raises(ValueError, match="window=0.*page_table"):
         attention_prefill(p, jnp.zeros((1, 3, 32)), cache,
-                          num_heads=2, kv_heads=2, head_dim=16, window=8,
+                          num_heads=2, kv_heads=2, head_dim=16, window=0,
                           page_table=table)
 
 
@@ -286,6 +287,16 @@ def _smoke_pair(arch="qwen1.5-0.5b", *, sparsity=0.5):
                          blocking=BlockingSpec(bk=32, bn=32), min_size=1024)
     packed = pack_params(params, sel.masks, sel.structures)
     return cfg, params, packed
+
+
+def _window_moe_smoke(window=8):
+    """A mellum2-shaped smoke stack: sliding and full GQA layers (3:1),
+    YaRN on the full one, every MLP a dropless MoE holding 4 of 8
+    experts."""
+    cfg = make_smoke(get_config("mellum2-12b-a2.5b"), n_layers=4,
+                     window=window, kv_heads=2).replace(
+        moe_experts=8, moe_top_k=2, moe_experts_held=4, moe_expert_offset=2)
+    return cfg, init_params(jax.random.PRNGKey(0), cfg)
 
 
 def _solo(cfg, params, prompt, gen, eos_id=None):
@@ -637,7 +648,7 @@ def test_engine_chunked_eos_freezes_midchunk_and_readmits():
 # Steady-state invariants (DESIGN.md §14): 0 recompiles, 1 transfer/chunk
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind", ["dense", "packed"])
+@pytest.mark.parametrize("kind", ["dense", "packed", "window-moe"])
 def test_engine_steady_state_zero_recompiles_one_sync_per_chunk(kind):
     """After warm-up, N chunks of mixed admit/retire traffic must hit the
     jit cache every time (0 new compiles, engine-wide compile-event
@@ -647,8 +658,11 @@ def test_engine_steady_state_zero_recompiles_one_sync_per_chunk(kind):
     so any undeclared device->host pull raises."""
     from repro.analysis import runtime as analysis_runtime
 
-    cfg, dense_p, packed_p = _smoke_pair()
-    params = dense_p if kind == "dense" else packed_p
+    if kind == "window-moe":          # windowed walk + MoE counters too
+        cfg, params = _window_moe_smoke(window=4)
+    else:
+        cfg, dense_p, packed_p = _smoke_pair()
+        params = dense_p if kind == "dense" else packed_p
     rng = np.random.default_rng(7)
     PLEN, GEN = 6, 3                   # one shape bucket for every request
 
@@ -747,12 +761,45 @@ def test_engine_attention_page_counters_hand_count():
     eng.submit(rng.integers(0, cfg.vocab, size=3).astype(np.int32), 3)
     eng.run()
     assert eng.decode_ticks == 4
-    assert eng.attn_live_page_ticks == 10
+    assert eng.attn_live_page_ticks == {"full": 10}
     pps = decode_pages_per_step(cfg.kv_heads, 4, cfg.head_dim_(),
                                 jnp.float32, eng.max_pages)
     walk = -(-eng.max_pages // pps) * pps
-    assert eng.attn_walked_page_ticks == eng.decode_ticks * 2 * walk
-    assert eng.attn_live_page_ticks <= eng.attn_walked_page_ticks
+    assert eng.attn_walked_page_ticks == {"full": eng.decode_ticks * 2 * walk}
+    assert eng.moe_routed_pairs == eng.moe_experts_touched == 0
+
+
+def test_engine_window_and_moe_counters_hand_count():
+    """The same traffic on a stack of sliding (window 4) and full layers.
+    A sliding layer's live pages run from the page of the first visible
+    position, ``max(ctx - 3, 0)``: A at ctx 5, 6, 7, 8 sees pages
+    {0,1}, {0,1}, {1}, {1}; B at 3, 4 sees {0}, {0}, then its slot is
+    free.  Live: 2+2+1+1 + 1+1.  The walk covers decode_blocks(...)
+    blocks of the window.  Every MoE layer routes both rows each tick:
+    at most min(top_k, held) pairs per row, and touches at most the 4
+    held experts."""
+    from repro.kernels.paged_attention import (decode_blocks,
+                                               decode_pages_per_step)
+
+    cfg, params = _window_moe_smoke(window=4)
+    rng = np.random.default_rng(13)
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=4,
+                        max_seq_len=16, ticks_per_sync=2)
+    eng.submit(rng.integers(0, cfg.vocab, size=5).astype(np.int32), 4)
+    eng.submit(rng.integers(0, cfg.vocab, size=3).astype(np.int32), 3)
+    eng.run()
+    assert eng.decode_ticks == 4
+    assert eng.attn_live_page_ticks == {"sliding": 8, "full": 10}
+    pps = decode_pages_per_step(cfg.kv_heads, 4, cfg.head_dim_(),
+                                jnp.float32, eng.max_pages)
+    for kind, window in (("sliding", 4), ("full", None)):
+        walk = decode_blocks(eng.max_pages, pps, 4, window) * pps
+        assert eng.attn_walked_page_ticks[kind] == eng.decode_ticks * 2 * walk
+    layer_ticks = eng.decode_ticks * eng.moe_layers
+    assert eng.moe_layers == 4
+    assert 0 < eng.moe_routed_pairs <= layer_ticks * 2 * cfg.moe_top_k
+    assert 0 < eng.moe_experts_touched <= layer_ticks * 4
+    assert eng.moe_experts_touched <= eng.moe_routed_pairs
 
 
 # ---------------------------------------------------------------------------
