@@ -11,6 +11,7 @@ altered token lies below the best logit by about the logits' spread (~1).
 import json
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 import repro.serving.engine as engine_mod
@@ -105,3 +106,86 @@ def test_run_fails_without_a_tpu():
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"})
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
+
+
+class _ScriptedServer:
+    """What the harness reads of the engine, scripted: two slots, one
+    token per slot a step, the first token at admission, and then a stall
+    after ``steps`` steps (the window closes with requests mid-stream and
+    waiting).  A request of one token finishes inside the step that admits
+    it, and the one named ``cancel`` is cancelled while it waits: neither
+    ever shows in ``emitted``."""
+
+    def __init__(self, steps: int, cancel: int):
+        from repro.serving.scheduler import Request, RequestStatus
+
+        self._Request, self._Status = Request, RequestStatus
+        self.steps, self.cancel = steps, cancel
+        self.waiting, self.slots, self.reqs = [], [None] * 2, {}
+        self.emitted_now = {}
+
+    def submit(self, prompt, max_new):
+        rid = len(self.reqs)
+        self.reqs[rid] = self._Request(rid=rid, prompt=prompt, max_new=max_new)
+        self.waiting.append(rid)
+        return rid
+
+    def _finish(self, rid, status, tokens):
+        req = self.reqs[rid]
+        req.status, req.tokens = status, np.arange(tokens, dtype=np.int32)
+
+    def step(self):
+        if self.steps == 0:
+            return
+        self.steps -= 1
+        if self.cancel in self.waiting:
+            self.waiting.remove(self.cancel)
+            self._finish(self.cancel, self._Status.CANCELLED, 0)
+        for i, rid in enumerate(self.slots):
+            if rid is None:
+                continue
+            self.emitted_now[rid] += 1
+            if self.emitted_now[rid] == self.reqs[rid].max_new:
+                self._finish(rid, self._Status.FINISHED, self.emitted_now.pop(rid))
+                self.slots[i] = None
+        for i in range(len(self.slots)):
+            while self.slots[i] is None and self.waiting:
+                rid = self.waiting.pop(0)
+                req = self.reqs[rid]
+                req.status, req.first_token_time = self._Status.ACTIVE, 1.0 + rid
+                if req.max_new == 1:
+                    self._finish(rid, self._Status.FINISHED, 1)
+                else:
+                    self.slots[i], self.emitted_now[rid] = rid, 1
+
+    def busy(self):
+        return bool(self.waiting) or any(s is not None for s in self.slots)
+
+    def emitted(self):
+        return dict(self.emitted_now)
+
+    def request(self, rid):
+        return self.reqs[rid]
+
+    def counters(self):
+        return {}
+
+
+def test_window_records_requests_finished_between_stamps_and_waiting():
+    """Request 0 (one token) finishes inside the step that admits it and
+    request 3 is cancelled while waiting: neither ever holds a slot in
+    ``emitted``, and both are recorded with their status and tokens.
+    Requests 4 and 5 hold slots at the close, 6 to 8 still wait."""
+    budgets = [1, 3, 2, 4, 9, 9, 9, 9, 9]
+    server = _ScriptedServer(steps=4, cancel=3)
+    requests = [traffic.Request(0.0, np.zeros(4, np.int32), m) for m in budgets]
+    records, *_ = serve.serve(server, requests, 0.3)
+    got = {rid: (r.status, len(r.stamps), r.first) for rid, r in records.items()}
+    assert got == {
+        0: ("finished", 1, 1.0), 1: ("finished", 3, 2.0), 2: ("finished", 2, 3.0),
+        3: ("cancelled", 0, None), 4: ("active", 3, 5.0), 5: ("active", 2, 6.0),
+        6: ("queued", 0, None), 7: ("queued", 0, None), 8: ("queued", 0, None)}
+    assert [len(records[r].tokens) for r in (0, 1, 2, 3)] == [1, 3, 2, 0]
+    assert all(records[r].tokens is None for r in (4, 5, 6, 7, 8))
+    stamps = sorted({t for r in records.values() for t in r.stamps})
+    assert len(stamps) == 4 and records[0].stamps == stamps[:1]

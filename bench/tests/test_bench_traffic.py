@@ -1,8 +1,38 @@
 """The traffic generator: every seed gets the same work in another order."""
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from bench import traffic
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+def _accepted_tok_s():
+    """The highest output_tok_s that an accepted line of PERF_LEDGER.jsonl
+    reads for a cell on each traffic mix, on either side of the line."""
+    best = {}
+    ledger = ROOT / "PERF_LEDGER.jsonl"
+    lines = ledger.read_text().splitlines() if ledger.exists() else []
+    for line in filter(str.strip, lines):
+        rec = json.loads(line)
+        if rec.get("verdict") != "accepted" or not rec.get("traffic"):
+            continue
+        for v in rec.get("end_to_end", {}).get("output_tok_s", []):
+            if v is not None:
+                best[rec["traffic"]] = max(best.get(rec["traffic"], 0.0), v)
+    return best
+
+
+ACCEPTED_TOK_S = _accepted_tok_s()
+# every backlog mix a cell uses, once a cell on it has an accepted line
+BACKLOG_MIXES = sorted({
+    w["traffic"] for w in BENCH["workloads"]
+    if traffic.load(w["traffic"])["arrivals"]["kind"] == "backlog"}
+    & set(ACCEPTED_TOK_S))
 
 SEEDS = [0, 7, 2**31 + 12345, 2**40 + 3]
 # an open-loop mix: Poisson arrivals, heavy-tailed prompts and outputs
@@ -71,3 +101,18 @@ def test_poisson_rate_and_window():
     assert len(run) == 80
     dues = [r.due for r in run]
     assert dues[0] == 0.0 and dues == sorted(dues) and dues[-1] < 40.0
+
+
+@pytest.mark.parametrize("mix_name", BACKLOG_MIXES)
+def test_backlog_outlasts_four_times_the_accepted_rate(mix_name):
+    """A closed backlog that drains inside the window caps output_tok_s at
+    its budgeted tokens over the window, and leaves the traced span at the
+    window's end without a decode chunk to read.  So a backlog mix holds
+    at least four times what the best accepted cell on it serves in a
+    window.  The rate is read from the ledger, so the test tightens as
+    faster changes are accepted: a change that takes a cell past a quarter
+    of its mix's drain rate deepens the mix first."""
+    mix = traffic.load(mix_name)
+    budget = sum(r.max_new for r in traffic.generate(
+        mix, 2, 0, BENCH["run_seconds"]))
+    assert budget / BENCH["run_seconds"] >= 4 * ACCEPTED_TOK_S[mix_name]

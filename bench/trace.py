@@ -69,18 +69,22 @@ class Reduced:
     def op_time(self, op_pattern: str, module_pattern: str = "") -> float:
         """Device seconds of operations matching ``op_pattern`` that ran
         inside an execution of a program matching ``module_pattern``."""
-        orx, mrx = re.compile(op_pattern), re.compile(module_pattern)
+        return sum(b - a for d in self.devices
+                   for _, a, b, _ in _ops_in(d, op_pattern, module_pattern)
+                   ) * 1e-9
+
+    def op_union(self, op_pattern: str, module_pattern: str = "") -> float:
+        """Device seconds in which some operation matching ``op_pattern``
+        ran inside an execution of a program matching ``module_pattern``:
+        the union of their intervals, each cut to its execution, so that
+        operations that overlap (an asynchronous copy's start and done)
+        count once and the result never exceeds the programs' time."""
         total = 0
         for d in self.devices:
-            spans = sorted((a, b) for name, a, b in d.modules
-                           if mrx.search(name))
-            starts = np.asarray([a for a, _ in spans], np.int64)
-            for name, a, b in d.ops:
-                if not orx.search(name):
-                    continue
-                i = int(np.searchsorted(starts, a, side="right")) - 1
-                if i >= 0 and a < spans[i][1]:
-                    total += b - a
+            cut = [(name, max(a, s), min(b, e))
+                   for name, a, b, (s, e) in _ops_in(d, op_pattern,
+                                                     module_pattern)]
+            total += _union(cut, 0, np.iinfo(np.int64).max)
         return total * 1e-9
 
     def kernel_time(self, kernel: str, module_pattern: str = "") -> float:
@@ -128,6 +132,21 @@ class Reduced:
                     best, cover = name, c
             out.append([best, (b - a) * 1e-9])
         return out
+
+
+def _ops_in(d: Timeline, op_pattern: str, module_pattern: str):
+    """(name, start, end, execution) of each operation on ``d`` matching
+    ``op_pattern`` that starts inside an execution (start, end) of a
+    program matching ``module_pattern``."""
+    orx, mrx = re.compile(op_pattern), re.compile(module_pattern)
+    spans = sorted((a, b) for name, a, b in d.modules if mrx.search(name))
+    starts = np.asarray([a for a, _ in spans], np.int64)
+    for name, a, b in d.ops:
+        if not orx.search(name):
+            continue
+        i = int(np.searchsorted(starts, a, side="right")) - 1
+        if i >= 0 and a < spans[i][1]:
+            yield name, a, b, spans[i]
 
 
 def _merged(ops: Sequence[Tuple[str, int, int]], lo: int, hi: int):
