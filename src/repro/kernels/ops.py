@@ -34,6 +34,8 @@ from .paged_attention import (
     paged_attention_decode_ref,
     paged_attention_prefill_pallas,
     paged_attention_prefill_ref,
+    paged_kv_write_pallas,
+    paged_kv_write_ref,
 )
 from .structure_norms import structure_norms_pallas
 from . import ref as _ref
@@ -41,7 +43,8 @@ from . import ref as _ref
 __all__ = [
     "Epilogue", "apply_epilogue", "make_epilogue",
     "bsr_matmul", "bsr_planes_matmul", "structure_norms", "on_tpu",
-    "paged_attention_decode", "paged_attention_prefill", "moe_experts",
+    "paged_attention_decode", "paged_attention_prefill", "paged_kv_write",
+    "moe_experts",
 ]
 
 
@@ -138,6 +141,28 @@ def paged_attention_decode(
     return paged_attention_decode_pallas(
         q, k_new, v_new, k_pool, v_pool, page_table, cache_len,
         interpret=(mode == "interpret"), window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("mode",))
+def paged_kv_write(
+    k_new: jnp.ndarray,        # (B, K, dh) — rotated K, new token
+    v_new: jnp.ndarray,        # (B, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) physical pages
+    v_pool: jnp.ndarray,
+    page_table: jnp.ndarray,   # (B, max_pages) int32 pool ids
+    cache_len: jnp.ndarray,    # (B,) int32 — the new token's position
+    *,
+    mode: str = "auto",
+):
+    """The new token's K/V into each row's page at ``cache_len``; returns
+    (k_pool, v_pool).  The ref path is an XLA scatter; the Pallas kernel
+    aliases the pools and moves one page per row, so a decode loop that
+    carries the pools updates them in place."""
+    if _use_ref(mode):
+        return paged_kv_write_ref(k_new, v_new, k_pool, v_pool, page_table,
+                                  cache_len)
+    return paged_kv_write_pallas(k_new, v_new, k_pool, v_pool, page_table,
+                                 cache_len, interpret=(mode == "interpret"))
 
 
 @functools.partial(

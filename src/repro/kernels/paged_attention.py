@@ -70,6 +70,15 @@ Masked positions never touch values: scores get the finite ``NEG_INF``
 sentinel *and* the value contribution is zeroed (``p`` is where-masked),
 so NaN poison in unallocated pages (the null page, freed pages) cannot
 leak through a ``0 · NaN`` in the value contraction.
+
+**The decode write** (``paged_kv_write``) puts each row's new K/V at
+its page ``page_table[b, cache_len // ps]``, offset ``cache_len % ps``,
+before the walk reads the pool.  The ``*_ref`` is an XLA scatter; for it
+XLA holds a scanned pool in another layout than the Mosaic walk takes,
+and copies every pool each tick.  The ``*_pallas`` kernel instead
+aliases the pools (``input_output_aliases``): grid (B,), the page id and
+offset scalar-prefetched, one page per row read, its one position
+replaced and written back — the pool is updated in place.
 """
 from __future__ import annotations
 
@@ -88,6 +97,8 @@ __all__ = [
     "paged_attention_decode_pallas",
     "paged_attention_prefill_ref",
     "paged_attention_prefill_pallas",
+    "paged_kv_write_ref",
+    "paged_kv_write_pallas",
     "prefill_pages_per_step",
 ]
 
@@ -120,6 +131,97 @@ def _check_page_tiling(pool: jnp.ndarray) -> None:
         raise ValueError(
             f"page_size {pool.shape[2]} is not a multiple of {sublanes}, the "
             f"sublane tiling of a {pool.dtype} KV pool on TPU")
+
+
+# ---------------------------------------------------------------------------
+# Decode write: each row's new K/V into its page, in place
+# ---------------------------------------------------------------------------
+
+def _write_slot(page_table: jnp.ndarray, cache_len: jnp.ndarray,
+                page_size: int):
+    """(page id, offset) (B,) of each row's next token: its own table
+    entry at logical page ``cache_len // page_size``.  A row whose
+    position lies past its table (a frozen row at a full budget) writes
+    the null page, page 0: a Pallas block index is not bounds-checked,
+    and the scatter would drop that write."""
+    idx = cache_len // page_size
+    last = page_table.shape[1] - 1
+    pid = jnp.take_along_axis(
+        page_table, jnp.minimum(idx, last)[:, None], axis=1)[:, 0]
+    return jnp.where(idx <= last, pid, 0), cache_len % page_size
+
+
+def paged_kv_write_ref(
+    k_new: jnp.ndarray,        # (B, K, dh) — rotated K of the new token
+    v_new: jnp.ndarray,        # (B, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh) physical pages
+    v_pool: jnp.ndarray,       # (P, K, page_size, dh)
+    page_table: jnp.ndarray,   # (B, max_pages) int32
+    cache_len: jnp.ndarray,    # (B,) int32 — the new token's position
+):
+    """The new token's K/V scattered into both pools; returns them."""
+    pid, off = _write_slot(page_table, cache_len, k_pool.shape[2])
+    return (k_pool.at[pid, :, off].set(k_new.astype(k_pool.dtype)),
+            v_pool.at[pid, :, off].set(v_new.astype(v_pool.dtype)))
+
+
+def _kv_write_kernel(pid_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
+                     ko_ref, vo_ref):
+    """Grid (B,): row ``b``'s page of each pool with position ``off[b]``
+    replaced by its new (1, K, 1, dh) row."""
+    del pid_ref                 # consumed by the page index maps
+    pos = jax.lax.broadcasted_iota(jnp.int32, kp_ref.shape, 2)
+    hit = pos == off_ref[pl.program_id(0)]
+    ko_ref[...] = jnp.where(hit, kn_ref[...], kp_ref[...])
+    vo_ref[...] = jnp.where(hit, vn_ref[...], vp_ref[...])
+
+
+def paged_kv_write_pallas(
+    k_new: jnp.ndarray,        # (B, K, dh)
+    v_new: jnp.ndarray,        # (B, K, dh)
+    k_pool: jnp.ndarray,       # (P, K, page_size, dh)
+    v_pool: jnp.ndarray,       # (P, K, page_size, dh)
+    page_table: jnp.ndarray,   # (B, max_pages) int32
+    cache_len: jnp.ndarray,    # (B,) int32
+    *,
+    interpret: bool = False,
+):
+    """The write of :func:`paged_kv_write_ref` with the pools aliased to
+    the outputs: one page per row and pool moves.  Rows may share a page
+    only where nothing reads what they wrote (free slots parked on the
+    null page): of two such writes one may be lost."""
+    b, kvh, dh = k_new.shape
+    ps = k_pool.shape[2]
+    if not interpret:
+        _check_page_tiling(k_pool)
+    pid, off = _write_slot(page_table, cache_len, ps)
+    # a unit sublane dim keeps the row block's trailing dims whole
+    kn = k_new.astype(k_pool.dtype).reshape(b, kvh, 1, dh)
+    vn = v_new.astype(v_pool.dtype).reshape(b, kvh, 1, dh)
+    row = pl.BlockSpec((1, kvh, 1, dh), lambda bb, pid, off: (bb, 0, 0, 0))
+    page = pl.BlockSpec((1, kvh, ps, dh),
+                        lambda bb, pid, off: (pid[bb], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[row, row, page, page],
+        out_specs=[page, page],
+    )
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
+        # operands count the two scalar-prefetch arrays: pools are 4, 5
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+        name="paged_kv_write",
+        **kwargs,
+    )(pid, off, kn, vn, k_pool, v_pool)
 
 
 # ---------------------------------------------------------------------------

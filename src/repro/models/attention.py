@@ -25,6 +25,7 @@ from repro.core.packing import BSRWeight
 from repro.distributed.sharding import logical_constraint
 from repro.kernels.ops import paged_attention_decode as _paged_decode_op
 from repro.kernels.ops import paged_attention_prefill as _paged_prefill_op
+from repro.kernels.ops import paged_kv_write as _paged_write_op
 from .layers import apply_mrope, apply_rope, dense, dense_init
 
 __all__ = [
@@ -426,15 +427,10 @@ def attention_decode(
             q = apply_rope(q, pos, theta=rope_theta, rope=rope)
             knew = apply_rope(knew, pos, theta=rope_theta, rope=rope)
         if paged:
-            # physical slot of this row's next token: its own page table
-            # entry at logical page cache_len // page_size
-            pid = jnp.take_along_axis(
-                page_table, (cache_len // page_size)[:, None], axis=1)[:, 0]
-            off = cache_len % page_size
-            ck = cache["k"].at[pid, :, off].set(
-                knew[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[pid, :, off].set(
-                vnew[:, 0].astype(cache["v"].dtype))
+            # this row's next token lands in its own page table entry at
+            # logical page cache_len // page_size: in place on TPU
+            ck, cv = _paged_write_op(knew[:, 0], vnew[:, 0], cache["k"],
+                                     cache["v"], page_table, cache_len)
         else:
             rows = jnp.arange(b)
             ck = cache["k"].at[rows, write_pos].set(

@@ -26,10 +26,10 @@ Its host loop interleaves three things per scheduler event:
    (tail prefill + decode) lands in privately allocated pages — COW is
    unreachable on this path, but a refcount guard before every decode
    chunk enforces it (``pool.cow``) as a backstop.
-2. **decode** — ONE jitted ``_decode_chunk`` call scans
+2. **decode** — ONE jitted ``_decode_chunk`` call loops over
    ``ticks_per_sync`` decode steps for all slots on device: per-row
    ``cache_len`` masks, per-row page-table reads/writes, per-slot
-   *traced* sampling params, per-slot PRNG keys advancing in-scan, and
+   *traced* sampling params, per-slot PRNG keys advancing in-loop, and
    per-slot ``done`` masks that freeze EOS'd / budget-exhausted rows
    mid-chunk.  One device->host transfer returns the whole token block
    plus per-row emitted counts — the per-token host sync of PR 4 is
@@ -167,8 +167,8 @@ def _paged_prefill_step(params, tokens, caches, table, slot, *, cfg,
 def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
                   temperature, top_k, top_p, budget_left, *,
                   cfg, ticks, eos_id, sampled, guard):
-    """``ticks`` batched decode steps in ONE ``lax.scan`` — the chunk
-    between two scheduler events (DESIGN.md §10).
+    """Up to ``ticks`` batched decode steps in ONE ``lax.while_loop`` —
+    the chunk between two scheduler events (DESIGN.md §10).
 
     Per-row ``done`` masks freeze rows mid-chunk the moment they emit
     ``eos_id`` or exhaust ``budget_left``: a frozen row keeps its token,
@@ -177,11 +177,13 @@ def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
     bit-identical to its solo decode no matter where in a chunk it
     finished.  Sampling params are traced ``(B,)`` vectors — co-batched
     requests keep independent temperature/top-k/top-p — and per-row rngs
-    advance in-scan only on live sampled rows.  ``sampled=False`` (a
+    advance in-loop only on live sampled rows.  ``sampled=False`` (a
     static host decision: no live slot has temperature > 0) compiles the
     pure-argmax variant with none of the per-row filter argsorts.  Once
-    every row is done the remaining steps skip the decode body via
-    ``lax.cond``.
+    every row is done the loop exits: the remaining ticks emit each row's
+    last token as not live.  Each tick updates the KV pools in place (the
+    aliased page write of ``ops.paged_kv_write`` on TPU), so no tick
+    copies a pool.
 
     ``guard=True`` (static) adds the non-finite fault gate (DESIGN.md
     §13): a row whose logits contain NaN/inf at some tick is frozen AT
@@ -235,19 +237,29 @@ def _decode_chunk(params, caches, tok, cache_len, tables, rngs,
         moe_sum = moe_sum + jnp.stack([moe["pairs"], moe["touched"]])
         return (tok, clen, rngs, done, bad, left, moe_sum, cs), (emit, live)
 
-    def step(carry, _):
-        return jax.lax.cond(
-            jnp.all(carry[3]),
-            lambda op: (op, (op[0][:, 0], jnp.zeros((b,), bool))),
-            live_step, carry)
+    def running(state):
+        i, carry, _, _ = state
+        return (i < ticks) & ~jnp.all(carry[3])
 
-    # the MoE counts ride the carry: as stacked scan outputs they changed
-    # XLA's buffer assignment of the pools (the qwen chunk's temporaries
-    # 4.4 -> 12.5 GB, half its throughput on the chip)
+    def tick(state):
+        i, carry, toks, lives = state
+        carry, (emit, live) = live_step(carry)
+        return i + 1, carry, toks.at[i].set(emit), lives.at[i].set(live)
+
+    # the all-done skip is the loop's exit, not a lax.cond: a cond arm
+    # that returns the pools untouched makes XLA copy them in one of its
+    # arms (the live one, in mellum2's chunk).  The MoE counts ride the
+    # carry: as stacked scan outputs they changed XLA's buffer assignment
+    # of the pools (the qwen chunk's temporaries 4.4 -> 12.5 GB, half its
+    # throughput on the chip)
     carry0 = (tok, cache_len, rngs, done0, bad0, budget_left,
               jnp.zeros((2,), jnp.int32), caches)
-    (tok, cache_len, rngs, _, bad, _, moe, caches), (toks, lives) = \
-        jax.lax.scan(step, carry0, None, length=ticks)
+    n, (tok, cache_len, rngs, _, bad, _, moe, caches), toks, lives = \
+        jax.lax.while_loop(running, tick, (
+            jnp.int32(0), carry0, jnp.zeros((ticks, b), tok.dtype),
+            jnp.zeros((ticks, b), bool)))
+    # ticks after every row is done emit each row's last token, not live
+    toks = jnp.where(jnp.arange(ticks)[:, None] < n, toks, tok[:, 0])
     counts = jnp.sum(lives.astype(jnp.int32), axis=0)
     return toks, counts, bad, tok, cache_len, rngs, moe, caches
 

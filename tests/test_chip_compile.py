@@ -32,6 +32,7 @@ from repro.kernels.moe_experts import moe_experts_pallas
 from repro.kernels.paged_attention import (
     paged_attention_decode_pallas,
     paged_attention_prefill_pallas,
+    paged_kv_write_pallas,
 )
 
 BLOCK = 128
@@ -275,6 +276,14 @@ def _kernel_call(kernel, sharding):
         return (lambda q, *a: paged_attention_prefill_pallas(
             q, *a, window=window).astype(q.dtype),
             _sds((1, s, h, dh), "bfloat16", sharding), rest)
+    if kernel == "paged_kv_write":
+        b, h, dh, ps, mp = 8, 16, 64, 16, 16
+        pool = _sds((b * mp + 1, h, ps, dh), "float32", sharding)
+        rest = (_sds((b, h, dh), "bfloat16", sharding),
+                _sds((b, h, dh), "bfloat16", sharding), pool,
+                _sds((b, mp), "int32", sharding), _sds((b,), "int32", sharding))
+        return (lambda kp, kn, vn, vp, *a: paged_kv_write_pallas(
+            kn, vn, kp, vp, *a)[0], pool, rest)
     if kernel == "moe_experts":
         tm, tiles, d, f, e = 16, 4, 256, 128, 4
         rest = (_sds((e, d, f), "bfloat16", sharding),
@@ -297,7 +306,7 @@ def _kernel_call(kernel, sharding):
                                     "bsr_matmul", "bsr_planes_matmul",
                                     "paged_attention_decode_window",
                                     "paged_attention_prefill_window",
-                                    "moe_experts"])
+                                    "moe_experts", "paged_kv_write"])
 def test_kernels_keep_their_names_in_the_compiled_program(one_chip, kernel):
     """Each Pallas kernel compiles to a ``tpu_custom_call`` instruction
     named by its ``pallas_call(name=...)``, not after whichever jit
@@ -349,3 +358,96 @@ def test_dense_decode_chunk_keeps_its_temporaries_for_v5e(one_chip,
         cfg=cfg, ticks=8, eos_id=None, sampled=False, guard=True).compile()
     assert _named_kernels(compiled.as_text(), "paged_attention_decode") >= 1
     assert compiled.memory_analysis().temp_size_in_bytes <= 4.5e9
+
+
+def _computations(hlo: str) -> dict:
+    """The compiled module's computations: name -> instruction lines."""
+    out, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line.rstrip())
+        if head:
+            cur = out[head.group(1)] = []
+        elif cur is not None:
+            cur.append(line.strip())
+    return out
+
+
+def _reachable(comps: dict, roots) -> set:
+    """``roots`` and every computation they call, however nested."""
+    ref = re.compile(r"(?:calls|body|condition|to_apply|true_computation|"
+                     r"false_computation|branch_computations)=\{?([^}]+?)\}?"
+                     r"(?:, [a-z_]+=|$)")
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for group in ref.findall(line):
+                todo += [n.strip().lstrip("%") for n in group.split(",")]
+    return seen
+
+
+def _pool_copies(hlo: str, pool_shape) -> tuple:
+    """(inside a while body, elsewhere): the ``copy`` and ``copy-start``
+    instructions that move a whole fp32 pool of ``pool_shape``."""
+    comps = _computations(hlo)
+    bodies = [m.group(1) for lines in comps.values() for line in lines
+              for m in [re.search(r" while\(.*body=%([\w.\-]+)", line)] if m]
+    loop = _reachable(comps, bodies)
+    shape = "f32[" + ",".join(map(str, pool_shape)) + "]"
+    copy = re.compile(r"^(?:ROOT )?%[\w.\-]+ = (.*?) copy(?:-start)?\(")
+    counts = [0, 0]
+    for name, lines in comps.items():
+        for line in lines:
+            m = copy.match(line)
+            if m and shape in m.group(1):
+                counts[name not in loop] += 1
+    return tuple(counts)
+
+
+@pytest.mark.parametrize("model", ["qwen", "mellum2"])
+def test_decode_chunk_updates_its_pools_in_place_for_v5e(one_chip,
+                                                         monkeypatch, model):
+    """The cells' whole 8-tick ``_decode_chunk``, compiled with its Pallas
+    kernels for the described chip, writes each tick's K/V through the
+    aliased ``paged_kv_write`` and copies no pool inside its loop: an XLA
+    scatter there held the pools in a layout the Mosaic walk does not
+    take (a copy of every pool every tick), and an all-done ``lax.cond``
+    arm holding the pools copied them in one arm.  qwen (16 slots, 40-page
+    tables over a 641-page pool, dh 64) keeps its copies at the chunk's
+    entry and exit, its pools' resident layout; mellum2 (16 layers,
+    3073-page pool of K 4, dh 128, 192-page tables), whose pool is
+    already in the kernels' layout, copies none anywhere."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import init_params
+    from repro.serving.engine import _decode_chunk
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the kernels, not refs
+    if model == "qwen":
+        cfg, mp, n_pages = get_config("qwen1.5-0.5b"), 40, 641
+    else:
+        cfg = get_config("mellum2-12b-a2.5b").replace(
+            n_layers=16, moe_experts_held=8, moe_expert_offset=0)
+        mp, n_pages = MELLUM["mp"], MELLUM["pages"]
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    b, ps = 16, 16
+    shape = (n_pages, cfg.kv_heads, ps, cfg.head_dim_())
+    pool = _sds(shape, "float32", one_chip)
+    caches = [{"k": pool, "v": pool} for _ in range(cfg.n_layers)]
+    vec = lambda dt: _sds((b,), dt, one_chip)  # noqa: E731
+    hlo = _decode_chunk.lower(
+        params, caches, _sds((b, 1), "int32", one_chip), vec("int32"),
+        _sds((b, mp), "int32", one_chip), _sds((b, 2), "uint32", one_chip),
+        vec("float32"), vec("int32"), vec("float32"), vec("int32"),
+        cfg=cfg, ticks=8, eos_id=None, sampled=False,
+        guard=True).compile().as_text()
+    assert _named_kernels(hlo, "paged_kv_write") == cfg.n_layers
+    in_loop, elsewhere = _pool_copies(hlo, shape)
+    assert in_loop == 0
+    if model == "mellum2":
+        assert elsewhere == 0

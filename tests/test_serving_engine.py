@@ -20,6 +20,7 @@ from repro.models.attention import (
     attention_prefill,
 )
 from repro.serving import NULL_PAGE, PagePool, Request, Scheduler, ServingEngine
+from repro.serving.engine import _decode_chunk
 from repro.sparse import knapsack_prune, pack_params
 
 
@@ -800,6 +801,74 @@ def test_engine_window_and_moe_counters_hand_count():
     assert 0 < eng.moe_routed_pairs <= layer_ticks * 2 * cfg.moe_top_k
     assert 0 < eng.moe_experts_touched <= layer_ticks * 4
     assert eng.moe_experts_touched <= eng.moe_routed_pairs
+
+
+# ---------------------------------------------------------------------------
+# The decode chunk's loop: the all-done skip is its exit
+# ---------------------------------------------------------------------------
+
+def _chunk_tick_by_tick(args, static):
+    """``_decode_chunk`` as the scan of per-tick steps it is: ``ticks``
+    one-tick chunks threading the state, each tick whose rows are all
+    done emitting every row's token as not live.  Budgets alone end rows
+    here (no eos, no NaN), so the host's ``left - counts`` carries
+    ``done`` exactly."""
+    params, caches, tok, clen, tables, rngs, temp, topk, topp, left = args
+    toks, lives, bad, moe = [], [], None, 0
+    for _ in range(static["ticks"]):
+        t, c, b, tok, clen, rngs, m, caches = _decode_chunk(
+            params, jax.tree.map(jnp.copy, caches), tok, clen, tables, rngs,
+            temp, topk, topp, left, **{**static, "ticks": 1})
+        toks.append(t[0])
+        lives.append(c)
+        left = left - c
+        bad = b if bad is None else bad | b
+        moe = moe + m
+    counts = jnp.sum(jnp.stack(lives), axis=0)
+    return (jnp.stack(toks), counts, bad, tok, clen, rngs, moe, caches)
+
+
+@pytest.mark.parametrize("budget", [3, 0], ids=["done-at-tick-3", "all-done"])
+@pytest.mark.parametrize("kind", ["dense", "window-moe"])
+def test_decode_chunk_loop_matches_tick_by_tick(kind, budget):
+    """An 8-tick chunk's whole return tuple — tokens, counts, bad flags,
+    last token, cache_len, rngs, MoE counts and every pool — is
+    bit-identical to eight one-tick chunks, on the CPU reference path.
+    With budget 3 every row finishes at tick 3 of 8 and the loop exits
+    there: the last 5 rows of the token block repeat each row's last
+    token.  With budget 0 every row starts done and nothing runs."""
+    if kind == "window-moe":
+        cfg, params = _window_moe_smoke(window=4)
+    else:
+        cfg, params, _ = _smoke_pair()
+    rng = np.random.default_rng(21)
+    eng = ServingEngine(params, cfg, num_slots=3, page_size=4,
+                        max_seq_len=24, ticks_per_sync=2)
+    for n in (5, 7):
+        eng.submit(rng.integers(0, cfg.vocab, size=n).astype(np.int32), 12)
+    eng.step()                          # admitted, two ticks in; slot 2 free
+    left = np.array([budget, budget, 0], np.int32)
+    args, static = eng._chunk_call(left, 8)
+    args = (args[0], jax.tree.map(jnp.copy, args[1]), *args[2:])
+    static["sampled"] = True            # per-row rngs advance in the loop
+    args = (*args[:6], jnp.asarray([0.8, 1.0, 0.0], jnp.float32), *args[7:])
+    pools = jax.device_get(args[1])     # the chunk donates its own
+
+    want = _chunk_tick_by_tick(args, static)
+    got = _decode_chunk(*args, **static)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    toks, counts, _, tok, clen, _, moe, _ = got
+    np.testing.assert_array_equal(np.asarray(counts), [budget, budget, 0])
+    np.testing.assert_array_equal(np.asarray(clen),
+                                  np.asarray(args[3]) + counts)
+    np.testing.assert_array_equal(
+        np.asarray(toks)[budget:],
+        np.broadcast_to(np.asarray(tok)[:, 0], (8 - budget, 3)))
+    if budget == 0:
+        for g, w in zip(jax.tree.leaves(got[7]), jax.tree.leaves(pools)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert not np.asarray(moe).any()
 
 
 # ---------------------------------------------------------------------------
